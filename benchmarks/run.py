@@ -50,8 +50,26 @@ SHARD_GRID = [(d, n) for n in (100_000, 1_000_000) for d in (1, 2, 4, 8)]
 SHARD_SMOKE = [(2, 100_000)]
 
 
+def _check_chip_free() -> None:
+    """Refuse to start a jax child while this process holds a chip.
+
+    An accelerator belongs to one process at a time: a child started
+    after the parent initialised a TPU backend fails or hangs."""
+    if "jax" not in sys.modules:
+        return
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"this process holds the {jax.default_backend()} backend; "
+            "run device cells and child cells in separate invocations")
+
+
 def _run_scale_cell(shape: str, n: int, tier: str, *,
                     twin: int = 0, timeout: int = 900) -> dict:
+    _check_chip_free()
     backend = "device" if shape == "sensor" else "host"
     cmd = [sys.executable, "-m", "benchmarks.scale_cell",
            "--shape", shape, "--n", str(n), "--tier", tier,
@@ -71,6 +89,7 @@ def _run_scale_cell(shape: str, n: int, tier: str, *,
 
 
 def _run_shard_cell(devices: int, n: int, *, timeout: int = 1200) -> dict:
+    _check_chip_free()
     cmd = [sys.executable, "-m", "benchmarks.shard_cell",
            "--devices", str(devices), "--n", str(n)]
     env = dict(os.environ)
@@ -705,11 +724,13 @@ def main() -> None:
     snapshot(fast=fast)                 # detector x backend matrix
     if not fast:
         # separate process: needs 512 host devices before jax init
+        _check_chip_free()
         r = subprocess.run([sys.executable, "-m",
                             "benchmarks.bench_fsp_scale"],
                            capture_output=True, text=True, timeout=1800)
-        print(r.stdout[-2000:] if r.returncode == 0
-              else f"fsp_scale FAILED:\n{r.stderr[-2000:]}")
+        if r.returncode != 0:
+            sys.exit(f"fsp_scale FAILED:\n{r.stderr[-2000:]}")
+        print(r.stdout[-2000:])
     print(f"\nall benchmarks done in {time.time() - t0:.1f}s")
 
 

@@ -115,10 +115,10 @@ def run_cell(devices: int, n_triples: int, seed: int) -> dict:
     if devices > 1:
         import jax
 
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         assert len(jax.devices()) >= devices, \
             (len(jax.devices()), devices)
-        mesh = make_mesh_compat((devices,), ("data",))
+        mesh = make_mesh((devices,), ("data",))
         for cid in sharded.plan.split_classes:
             got = sharded.cross_shard_ami(cid, mesh=mesh)
             want = report["split_class_ami"][int(cid)]

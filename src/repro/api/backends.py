@@ -104,12 +104,10 @@ class ShardedBackend:
     On a real mesh the WHOLE candidate stack of a sweep runs through
     ``core.distributed.ami_bucketed_batch`` -- the explicit shard_map
     (hash-bucket all_to_all + psum) path with a leading candidate axis,
-    one lowering per descent.  The implicit GSPMD lowering of
-    the sort-based sweep silently miscounts distinct rows on multi-axis
-    meshes under jax 0.4.x (per-shard segment counts get summed across
-    replicas -- a latent seed bug: ``gfsp_distributed`` built the same
-    lowering but was only ever executed with ``mesh=None``), so the
-    collective schedule must be explicit.
+    one lowering per descent.  The collective schedule is explicit
+    because the implicit GSPMD lowering of the sort-based sweep was
+    seen to miscount distinct rows on multi-axis meshes (per-shard
+    segment counts summed across replicas).
     """
 
     name = "sharded"

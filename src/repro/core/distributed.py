@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .star import ami_device, edges_formula_device
 from .triples import TripleStore
 
@@ -174,9 +172,9 @@ def ami_bucketed_batch(objmat, valid, col_masks, mesh, *, dp_axes=("data",),
     spec_m = P(dp_axes, None)
     spec_v = P(dp_axes)
     # check_vma=False: pallas_call outputs do not carry vma metadata yet
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec_m, spec_v, P(None, None)),
-                     out_specs=P(None), check_vma=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec_m, spec_v, P(None, None)),
+                         out_specs=P(None), check_vma=False)(
         objmat, valid, col_masks)
 
 

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh as make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 _MAX_MODEL = 16
 
@@ -50,5 +50,4 @@ def remesh(n_devices: int | None = None, *, tp_pref: int | None = None,
         shape = (n_devices // tp_pref, tp_pref)
     else:
         shape = choose_mesh_shape(n_devices)
-    return make_mesh_compat(shape, ("data", "model"),
-                            devices=devices[:n_devices])
+    return make_mesh(shape, ("data", "model"), devices=devices[:n_devices])

@@ -400,6 +400,11 @@ class ShardedFactorizedGraph:
         import concurrent.futures
         import multiprocessing as mp
         from repro.api.snapshot import GraphSnapshot
+        if kw["backend"] != "host":
+            # a forked child cannot use a chip its parent holds
+            raise ValueError("parallel shard detection forks workers and "
+                             f"runs on the host backend only, not "
+                             f"{kw['backend']!r}")
         ctx = mp.get_context("fork")
         _FORK_CTX["snaps"] = self._snaps
         _FORK_CTX["kw"] = kw

@@ -21,7 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -236,5 +235,5 @@ def decode_attention_sharded(q, k, v, bias, *, mesh, seq_axis: str = "model",
     qs = q_spec if q_spec is not None else P(None, None, None, None)
     ks = kv_spec if kv_spec is not None else P(None, None, seq_axis, None)
     bs = bias_spec if bias_spec is not None else P(None, seq_axis)
-    return shard_map(body, mesh=mesh, in_specs=(qs, ks, ks, bs),
-                     out_specs=qs)(q, k, v, bias)
+    return jax.shard_map(body, mesh=mesh, in_specs=(qs, ks, ks, bs),
+                         out_specs=qs)(q, k, v, bias)

@@ -7,12 +7,18 @@ class multiplicities (Def. 4.5).
 
 The kernel is a blocked elementwise compare between the signature block and
 the one-row-shifted block (the wrapper materializes the shift, so no
-cross-block halo exchange is needed); each VMEM block also emits its partial
-boundary count so AMI can be accumulated without re-reading HBM.
+cross-block halo exchange is needed).  The segment count is the boundary
+vector summed in XLA.
 
-A ``(C, N, 2)`` per-candidate-sorted stack runs under grid ``(C, N / TILE_N)``
--- the candidate axis of one ``sweep_candidates`` lowering is a Pallas grid
-dimension, and the first-row-always-differs shift is materialized per
+Layout: the wrapper turns the ``(C, N, 2)`` row-major signatures into
+``(C, 2, N)`` hi/lo planes, so rows run along the 128-wide lane axis and a
+``(1, 2, TILE_N)`` block is two lane-dense rows (a ``(TILE_N, 2)`` block
+would fill 2 of 128 lanes).  The boundary output is ``(C, 1, N)`` with
+``(1, 1, TILE_N)`` blocks: its last two block dimensions are the full
+unit dimension and a multiple of 128, which the TPU lowering accepts at
+any C and N.  A 2-D ``(N, 2)`` input is the C = 1 case of the same launch;
+the candidate axis of one ``sweep_candidates`` lowering is the leading
+grid dimension, and the first-row-always-differs shift is materialized per
 candidate, so every candidate keeps its own segment count (and its own
 padded-sentinel segment, which the caller subtracts).
 """
@@ -24,22 +30,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_N = 2048
+TILE_N = 8192
+LANES = 128
 
 
-def _seg_kernel(cur_ref, prev_ref, bound_ref, partial_ref):
-    cur = cur_ref[...]
-    prev = prev_ref[...]
-    diff = jnp.any(cur != prev, axis=1).astype(jnp.int32)
-    bound_ref[...] = diff
-    partial_ref[...] = jnp.sum(diff, keepdims=True)
-
-
-def _seg_kernel_batched(cur_ref, prev_ref, bound_ref, partial_ref):
-    # block is (1, TILE_N, 2): one candidate's tile per grid cell
-    diff = jnp.any(cur_ref[0] != prev_ref[0], axis=1).astype(jnp.int32)
-    bound_ref[0] = diff
-    partial_ref[0] = jnp.sum(diff, keepdims=True)
+def _seg_kernel(cur_ref, prev_ref, bound_ref):
+    # blocks are (1, 2, tile): the hi and lo planes of one candidate's tile
+    cur = cur_ref[0]
+    prev = prev_ref[0]
+    diff = (cur[0:1] != prev[0:1]) | (cur[1:2] != prev[1:2])
+    bound_ref[0] = diff.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -50,48 +50,27 @@ def seg_boundaries(sig_sorted: jax.Array, interpret: bool = True
     A ``(C, N, 2)`` stack (each candidate sorted along its own row axis)
     maps to ``((C, N) boundaries, (C,) counts)`` in one launch.
     """
-    if sig_sorted.ndim == 3:
-        c, n, _ = sig_sorted.shape
-        prev = jnp.concatenate([~sig_sorted[:, :1], sig_sorted[:, :-1]],
-                               axis=1)
-        n_pad = -n % TILE_N
-        cur_p = jnp.pad(sig_sorted, ((0, 0), (0, n_pad), (0, 0)))
-        prev_p = jnp.pad(prev, ((0, 0), (0, n_pad), (0, 0)))
-        if n_pad:
-            prev_p = prev_p.at[:, n:].set(cur_p[:, n:])
-        grid = (c, cur_p.shape[1] // TILE_N)
-        bounds, partials = pl.pallas_call(
-            _seg_kernel_batched,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, TILE_N, 2), lambda ci, i: (ci, i, 0)),
-                      pl.BlockSpec((1, TILE_N, 2), lambda ci, i: (ci, i, 0))],
-            out_specs=[pl.BlockSpec((1, TILE_N), lambda ci, i: (ci, i)),
-                       pl.BlockSpec((1, 1), lambda ci, i: (ci, i))],
-            out_shape=[jax.ShapeDtypeStruct((c, cur_p.shape[1]), jnp.int32),
-                       jax.ShapeDtypeStruct((c, grid[1]), jnp.int32)],
-            interpret=interpret,
-        )(cur_p, prev_p)
-        return bounds[:, :n], partials.sum(axis=1)
-    n = sig_sorted.shape[0]
+    if sig_sorted.ndim == 2:
+        bounds, counts = seg_boundaries(sig_sorted[None], interpret=interpret)
+        return bounds[0], counts[0]
+    c, n, _ = sig_sorted.shape
+    # equality is sign-agnostic: compare the bits as int32 planes
+    cur = jnp.swapaxes(jax.lax.bitcast_convert_type(sig_sorted, jnp.int32),
+                       1, 2)                                   # (c, 2, n)
     # prev[i] = sig[i-1]; row 0 compares against ~sig[0] so it always differs
-    prev = jnp.concatenate([~sig_sorted[:1], sig_sorted[:-1]], axis=0)
-    n_pad = -n % TILE_N
-    cur_p = jnp.pad(sig_sorted, ((0, n_pad), (0, 0)))
+    prev = jnp.concatenate([~cur[:, :, :1], cur[:, :, :-1]], axis=2)
+    tile = min(TILE_N, -(-n // LANES) * LANES)
+    n_pad = -n % tile
     # pad prev with the same values as cur so padded rows never count
-    prev_p = jnp.pad(prev, ((0, n_pad), (0, 0)))
-    if n_pad:
-        cur_tail = cur_p[n:]
-        prev_p = prev_p.at[n:].set(cur_tail)
-    grid = (cur_p.shape[0] // TILE_N,)
-    bounds, partials = pl.pallas_call(
+    cur = jnp.pad(cur, ((0, 0), (0, 0), (0, n_pad)))
+    prev = jnp.pad(prev, ((0, 0), (0, 0), (0, n_pad)))
+    spec = pl.BlockSpec((1, 2, tile), lambda ci, i: (ci, 0, i))
+    bounds = pl.pallas_call(
         _seg_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((TILE_N, 2), lambda i: (i, 0)),
-                  pl.BlockSpec((TILE_N, 2), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((TILE_N,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((cur_p.shape[0],), jnp.int32),
-                   jax.ShapeDtypeStruct((grid[0],), jnp.int32)],
+        grid=(c, (n + n_pad) // tile),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((1, 1, tile), lambda ci, i: (ci, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((c, 1, n + n_pad), jnp.int32),
         interpret=interpret,
-    )(cur_p, prev_p)
-    return bounds[:n], partials.sum()
+    )(cur, prev)[:, 0, :n]
+    return bounds, bounds.sum(axis=1)

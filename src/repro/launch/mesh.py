@@ -9,7 +9,8 @@ Target hardware (roofline constants): TPU v5e-class chip.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh as make_mesh_compat  # noqa: F401
+import jax
+from jax.sharding import AxisType
 
 # hardware constants used by the roofline analysis (per chip)
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -17,12 +18,20 @@ HBM_BW = 819e9                  # bytes/s
 ICI_BW = 50e9                   # bytes/s per link direction
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: every sharding in this repo is
+    placed by GSPMD propagation or an explicit ``shard_map``, never by
+    explicit-sharding types (the ``jax.make_mesh`` default)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh for CPU tests (1 real device)."""
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)

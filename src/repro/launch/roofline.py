@@ -132,8 +132,6 @@ class Roofline:
 def analyze(compiled, *, n_chips: int, model_flops: float) -> Roofline:
     from . import hlo_cost as hc
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     cost = hc.hlo_cost(txt)           # loop-aware (see hlo_cost.py docstring)
     flops = cost.flops

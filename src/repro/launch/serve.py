@@ -36,6 +36,7 @@ import numpy as np
 import jax
 
 from repro.configs import get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.blocks import Ctx
 from repro.models.lm import LM
 from repro.serving import (GraphQueryRequest, GraphQueryService,
@@ -50,7 +51,7 @@ def serve_graph_queries(n_requests: int, *, n_observations: int = 600,
 
     store = generate(SensorGraphSpec(n_observations=n_observations,
                                      seed=seed))
-    comp = Compactor(detector="gfsp", backend="host")
+    comp = Compactor(detector="gfsp", backend=backend)
     comp.run(store)
     fg = comp.fgraph
     term = store.dict.term
@@ -125,9 +126,9 @@ def serve_sharded_queries(n_requests: int, *, n_shards: int = 4,
 
     store = generate(SensorGraphSpec(n_observations=n_observations,
                                      seed=seed))
-    snap, _ = CompactionPlanner("gfsp", "host").run(store.copy())
+    snap, _ = CompactionPlanner("gfsp", backend).run(store.copy())
     sharded = ShardedFactorizedGraph.partition(store.copy(), n_shards)
-    sharded.detect_all(backend="host")
+    sharded.detect_all(backend=backend)
     assert sharded.digest() == snap.digest(), \
         "sharded detection broke digest parity"
 
@@ -198,7 +199,7 @@ def serve_bgp_queries(n_requests: int, *, n_observations: int = 600,
     store = generate(SensorGraphSpec(n_observations=n_observations,
                                      seed=seed,
                                      include_sensor_metadata=True))
-    comp = Compactor(detector="gfsp", backend="host")
+    comp = Compactor(detector="gfsp", backend=backend)
     comp.run(store)
     fg = comp.fgraph
     rng = np.random.default_rng(seed)
@@ -516,7 +517,8 @@ def main(argv=None) -> dict:
                          "the replicated endpoint")
     ap.add_argument("--graph-backend", default="host",
                     choices=("host", "device"),
-                    help="molecule-match backend for --graph-queries")
+                    help="detection and molecule-match backend for the "
+                         "graph endpoints")
     ap.add_argument("--online", action="store_true",
                     help="soak the online compaction service (mixed "
                          "ingest batches + drift-tracked re-detection) "
@@ -532,6 +534,7 @@ def main(argv=None) -> dict:
                          "a random injection site) on a fresh --durable "
                          "run; restart the same command to recover")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.online:
         return serve_online(args.online_batches, seed=args.seed,

@@ -31,6 +31,7 @@ from repro.ckpt import Checkpointer, latest_step
 from repro.configs import get_arch, reduced
 from repro.data.lm_pipeline import LMPipeline, PipelineSpec
 from repro.dist.compression import make_pod_compress_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.blocks import Ctx
 from repro.models.lm import LM
 from repro.train import make_optimizer, make_train_step
@@ -59,6 +60,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -21,7 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.kernels import ops as kops
 
 from .common import TSpec, rms_norm, rope, shard_hint
@@ -374,7 +373,7 @@ def _moe_shard_map(ctx: Ctx, p: Params, x):
 
     x_spec = P(dp_axes if batch_sharded else None, None, None)
     w_spec = P("model", None, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()))(

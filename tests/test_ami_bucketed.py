@@ -20,9 +20,9 @@ import jax, jax.numpy as jnp
 sys.path.insert(0, "src")
 from repro.core.distributed import ami_bucketed, pad_rows, shard_rows
 from repro.core.star import ami
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rng = np.random.default_rng(7)
 out = []
 for n, k, card in [(1000, 4, 13), (97, 3, 2), (4096, 2, 300)]:

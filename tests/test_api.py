@@ -49,12 +49,12 @@ import numpy as np, jax
 sys.path.insert(0, "src")
 from repro.api import Compactor, ShardedBackend
 from repro.data.synthetic import SensorGraphSpec, generate
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 store = generate(SensorGraphSpec(n_observations=403, seed=2))
 cid = store.dict.lookup("ssn:Observation")
 host = Compactor(detector="gfsp", backend="host").detect(store, cid)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 be = ShardedBackend(mesh=mesh)
 assert be.plan.dp_axes == ("data",), be.plan.dp_axes   # tp axis excluded
 sh = Compactor(detector="gfsp", backend=be).detect(store, cid)

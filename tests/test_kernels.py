@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_scan import linear_scan
-from repro.kernels.seg_count import seg_boundaries
+from repro.kernels.seg_count import TILE_N, seg_boundaries
 from repro.kernels.sig_hash import sig_hash
 
 
@@ -61,6 +61,25 @@ def test_seg_boundaries_matches_ref(n):
     want = ref.seg_boundaries_ref(sig)
     np.testing.assert_array_equal(np.asarray(bounds), np.asarray(want))
     assert int(count) == int(want.sum())
+
+
+@pytest.mark.parametrize("c,n", [(2, 100), (3, TILE_N + 1),
+                                 (2, 2 * TILE_N + 7)])
+def test_seg_boundaries_batched_matches_ref(c, n):
+    """(C, N, 2) stacks: per-candidate boundaries and counts, with N
+    spanning several row tiles."""
+    rng = np.random.default_rng(c * 100_003 + n)
+    sig = rng.integers(0, 7, (c, n, 2)).astype(np.uint32)
+    sig = np.stack([s[np.lexsort((s[:, 1], s[:, 0]))] for s in sig])
+    bounds, counts = seg_boundaries(jnp.asarray(sig), interpret=True)
+    want = np.asarray(ref.seg_boundaries_ref(jnp.asarray(sig)))
+    assert bounds.shape == (c, n) and counts.shape == (c,)
+    np.testing.assert_array_equal(np.asarray(bounds), want)
+    np.testing.assert_array_equal(np.asarray(counts), want.sum(axis=1))
+    for ci in range(c):   # each candidate == its own 2-D launch
+        b1, n1 = seg_boundaries(jnp.asarray(sig[ci]), interpret=True)
+        np.testing.assert_array_equal(np.asarray(b1), want[ci])
+        assert int(n1) == int(counts[ci])
 
 
 def test_seg_boundaries_counts_groups():

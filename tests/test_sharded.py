@@ -117,6 +117,17 @@ def test_detect_fork_parallel_digest_parity_and_shared_dict():
     assert any(r["classes"] for r in report["shards"].values())
 
 
+@pytest.mark.parametrize("backend", ["device", "sharded"])
+def test_detect_fork_parallel_refuses_device_backends(backend):
+    """A forked worker cannot use a chip its parent holds: fork-parallel
+    detection is host-only and refuses before any worker starts."""
+    sharded = ShardedFactorizedGraph.partition(_sensor(), 2)
+    epochs = [s.epoch for s in sharded.snapshots]
+    with pytest.raises(ValueError, match="host backend only"):
+        sharded.detect_all(backend=backend, parallel=True)
+    assert [s.epoch for s in sharded.snapshots] == epochs
+
+
 def test_detect_bumps_epoch_and_swaps_atomically():
     store = _sensor()
     sharded = ShardedFactorizedGraph.partition(store, 2)
