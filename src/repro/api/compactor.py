@@ -40,6 +40,7 @@ from typing import Iterable, Sequence
 from repro.core.fgraph import FactorizedGraph
 from repro.core.gfsp import FSPResult
 from repro.core.triples import TripleStore
+from repro.online.metrics import span
 
 from .backends import ExecutionBackend
 from .detectors import Detector
@@ -123,7 +124,9 @@ class Compactor:
             classes: Iterable[int] | None = None, *,
             stream: bool = False) -> CompactionReport:
         """plan + execute in one call (the common entry point)."""
-        return self.execute(store, self.plan(store, classes, stream=stream))
+        with span("compact.run"):
+            return self.execute(store,
+                                self.plan(store, classes, stream=stream))
 
     # -- snapshot state ----------------------------------------------------
     @property

@@ -40,6 +40,7 @@ from repro.core.gfsp import FSPResult
 from repro.core.star import StarSweepResult, num_edges, star_groups
 from repro.core.sweep import MAX_SWEEP_CANDIDATES, pick_child
 from repro.core.triples import TripleStore
+from repro.online.metrics import span
 
 from .backends import ExecutionBackend, HostBackend, Registry, get_backend
 
@@ -66,7 +67,8 @@ def _class_setup(store: TripleStore, class_id: int,
 
 def _result(store, class_id, best: StarSweepResult, am: int,
             iterations: int, evaluations: int, t0: float) -> FSPResult:
-    fsp = star_groups(store, class_id, best.props) if best.props else []
+    with span("detect.extract", key=class_id):
+        fsp = star_groups(store, class_id, best.props) if best.props else []
     return FSPResult(
         class_id=class_id, props=best.props, edges=best.edges,
         ami=best.ami, am=am, iterations=iterations, evaluations=evaluations,

@@ -48,6 +48,7 @@ from repro.core.gfsp import FSPResult
 from repro.core.index import GraphIndex, in_sorted
 from repro.core.star import row_groups
 from repro.core.triples import TripleStore
+from repro.online.metrics import span
 
 from .backends import ExecutionBackend, get_backend
 from .detectors import Detector, get_detector
@@ -384,9 +385,18 @@ class CompactionPlanner:
                 else [int(c) for c in store.classes()])
         release = getattr(store, "release_transients", None) \
             if stream else None
+        with span("compact.plan"):
+            entries = self._plan_classes(store, cids, release)
+        entries.sort(key=lambda e: -e.predicted_savings)
+        return CompactionPlan(entries=entries, detector=self.detector.name,
+                              backend=self.backend.name)
+
+    def _plan_classes(self, store: TripleStore, cids: list[int],
+                      release) -> list[ClassPlan]:
         entries = []
         for cid in cids:
-            stats = store.class_stats(cid)
+            with span("detect.extract", key=cid):
+                stats = store.class_stats(cid)
             n_s = int(stats.properties.shape[0])
             am = stats.n_instances
             if n_s < 2 or am == 0:
@@ -403,35 +413,34 @@ class CompactionPlanner:
                 entries.append(entry)
             if release is not None:
                 release()
-        entries.sort(key=lambda e: -e.predicted_savings)
-        return CompactionPlan(entries=entries, detector=self.detector.name,
-                              backend=self.backend.name)
+        return entries
 
     # -- execution ---------------------------------------------------------
     def execute(self, store: TripleStore, plan: CompactionPlan, *,
                 epoch: int = 0) -> tuple[GraphSnapshot, CompactionReport]:
         """Factorize every planned class transactionally into a fresh
         snapshot.  The input store is never mutated."""
-        t0 = time.perf_counter()
-        pairs = [(e.class_id, e.props) for e in plan]
-        graph, results = factorize_classes(
-            store, pairs, surrogate_prefix=self.surrogate_prefix)
-        # star_objects rows are aligned with surrogates and ordered over
-        # sorted props -- the molecule tables build with no rescan of G'
-        fg = FactorizedGraph.from_compaction(graph, results)
+        with span("compact.execute") as sp:
+            pairs = [(e.class_id, e.props) for e in plan]
+            graph, results = factorize_classes(
+                store, pairs, surrogate_prefix=self.surrogate_prefix)
+            # star_objects rows are aligned with surrogates and ordered
+            # over sorted props -- the molecule tables build with no
+            # rescan of G'
+            fg = FactorizedGraph.from_compaction(graph, results)
         snap = GraphSnapshot(fgraph=fg, epoch=epoch)
         report = CompactionReport(
             graph=graph, plan=plan, factorizations=results,
             n_triples_before=store.n_triples, n_triples_after=graph.n_triples,
-            exec_time_ms=(time.perf_counter() - t0) * 1e3,
-            fgraph=fg)
+            exec_time_ms=sp.ms, fgraph=fg)
         return snap, report
 
     def run(self, store: TripleStore,
             classes: Iterable[int] | None = None
             ) -> tuple[GraphSnapshot, CompactionReport]:
         """plan + execute in one call (the common entry point)."""
-        return self.execute(store, self.plan(store, classes))
+        with span("compact.run"):
+            return self.execute(store, self.plan(store, classes))
 
     # -- incremental update ------------------------------------------------
     def apply_update(self, snapshot: GraphSnapshot,

@@ -18,6 +18,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.online.metrics import span
+
 from .star import row_groups
 from .triples import TripleStore
 
@@ -199,8 +201,9 @@ def factorize_classes(store: TripleStore,
     g = store
     results = []
     for class_id, props in plans:
-        res = _factorize(g, class_id, props,
-                         surrogate_prefix=surrogate_prefix)
+        with span("factorize.class", key=class_id):
+            res = _factorize(g, class_id, props,
+                             surrogate_prefix=surrogate_prefix)
         results.append(res)
         g = res.graph
     return g, results

@@ -48,6 +48,8 @@ from typing import Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
+from repro.online.metrics import span
+
 from .star import StarSweepResult, ami, num_edges, num_edges_batch
 from .triples import TripleStore
 
@@ -251,8 +253,9 @@ class _WorkspaceBase:
         self.n_s = int(n_s)
         self.am = int(am)
         self._all_props = tuple(int(p) for p in props)
-        self.entities, self.matrix = store.object_matrix(
-            class_id, self._all_props)
+        with span("detect.extract", key=self.class_id):
+            self.entities, self.matrix = store.object_matrix(
+                class_id, self._all_props)
         self._active = list(range(len(self._all_props)))
 
     @property
@@ -350,19 +353,20 @@ class DeviceSweepWorkspace(_WorkspaceBase):
         if self._dev is not None:
             return
         jax, jnp = _jax()
-        n, k = self.matrix.shape
-        row_multiple, sharding = self._placement(n)
-        self.n_bucket = bucket_rows(n, row_multiple)
-        self.k_bucket = bucket_cols(k)
-        buf = np.zeros((self.n_bucket, self.k_bucket), np.int32)
-        buf[:n, :k] = self.matrix
-        valid = np.arange(self.n_bucket) < n
-        if sharding is not None:
-            self._dev = jax.device_put(buf, sharding[0])
-            self._valid = jax.device_put(valid, sharding[1])
-        else:
-            self._dev = jnp.asarray(buf)
-            self._valid = jnp.asarray(valid)
+        with span("sweep.upload", key=self.class_id):
+            n, k = self.matrix.shape
+            row_multiple, sharding = self._placement(n)
+            self.n_bucket = bucket_rows(n, row_multiple)
+            self.k_bucket = bucket_cols(k)
+            buf = np.zeros((self.n_bucket, self.k_bucket), np.int32)
+            buf[:n, :k] = self.matrix
+            valid = np.arange(self.n_bucket) < n
+            if sharding is not None:
+                self._dev = jax.device_put(buf, sharding[0])
+                self._valid = jax.device_put(valid, sharding[1])
+            else:
+                self._dev = jnp.asarray(buf)
+                self._valid = jnp.asarray(valid)
 
     def sweep(self) -> tuple[np.ndarray, np.ndarray]:
         # the drop-one stack spans FULL bucket height so the compiled
@@ -376,13 +380,15 @@ class DeviceSweepWorkspace(_WorkspaceBase):
 
     def _run_batch(self, stack: np.ndarray, n_sp: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
-        """One lowering over a (c_b, k_b) padded stack."""
+        """One lowering over a (c_b, k_b) padded stack: dispatch through
+        the results' copy to the host."""
         _, jnp = _jax()
         EXEC_STATS["lowerings"] += 1
-        edges, amis = _bucket_sweep_fn(self.use_kernel)(
-            self._dev, self._valid, jnp.asarray(stack), self.am,
-            jnp.asarray(n_sp), self.n_s)
-        return np.asarray(edges), np.asarray(amis)
+        with span("sweep.batch", key=self.class_id):
+            edges, amis = _bucket_sweep_fn(self.use_kernel)(
+                self._dev, self._valid, jnp.asarray(stack), self.am,
+                jnp.asarray(n_sp), self.n_s)
+            return np.asarray(edges), np.asarray(amis)
 
     def sweep_candidates(self, col_masks) -> tuple[np.ndarray, np.ndarray]:
         masks = self._normalize_masks(col_masks)
@@ -450,6 +456,8 @@ class ShardedSweepWorkspace(DeviceSweepWorkspace):
         _, jnp = _jax()
         EXEC_STATS["lowerings"] += 1
         fn = _sharded_sweep_fn(self.mesh, self.dp_axes, self.use_kernel)
-        amis = np.asarray(fn(self._dev, self._valid, jnp.asarray(stack)))
+        with span("sweep.batch", key=self.class_id):
+            amis = np.asarray(fn(self._dev, self._valid,
+                                 jnp.asarray(stack)))
         edges = num_edges_batch(amis, self.am, n_sp, self.n_s)
         return edges, amis

@@ -26,11 +26,21 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro.online.metrics import span
+
 from .index import (GraphIndex, SPO_PERM, in_sorted, merge_disjoint,
                     setdiff_rows, sort_unique)
 
 RDF_TYPE = "rdf:type"
 INSTANCE_OF = "repro:instanceOf"
+
+
+def _sorted_unique(rows) -> np.ndarray:
+    """The store's (s, p, o) sort and dedup: what every ``from_ids``
+    without ``presorted`` and every rebinding of ``spo`` pays."""
+    with span("store.build"):
+        return sort_unique(np.asarray(rows, np.int32).reshape(-1, 3),
+                           SPO_PERM)
 
 
 class TermDict:
@@ -83,8 +93,9 @@ class TermDict:
         allocation order, so restoring the exact list restores the
         exact id assignment."""
         d = cls()
-        d._terms = list(terms)
-        d._index = {t: i for i, t in enumerate(d._terms)}
+        with span("dict.build"):
+            d._terms = list(terms)
+            d._index = {t: i for i, t in enumerate(d._terms)}
         if len(d._index) != len(d._terms):
             raise ValueError("duplicate terms in from_terms input")
         return d
@@ -143,7 +154,7 @@ class TripleStore:
         spo = np.asarray(spo, dtype=np.int32).reshape(-1, 3)
         # ``presorted=True``: caller guarantees sorted-unique (s, p, o)
         # rows (e.g. a row-subset of another store) -- skip the dedup sort
-        self._spo = spo if presorted else sort_unique(spo, SPO_PERM)
+        self._spo = spo if presorted else _sorted_unique(spo)
 
     # -- storage invariants ------------------------------------------------
     @property
@@ -154,15 +165,16 @@ class TripleStore:
     def spo(self, rows: np.ndarray) -> None:
         # rebinding the triple array invalidates the index (callers that
         # append should prefer ``add_ids``, which merges instead)
-        self._spo = sort_unique(np.asarray(rows, np.int32).reshape(-1, 3),
-                                SPO_PERM)
+        self._spo = _sorted_unique(rows)
         self._index = None
 
     @property
     def index(self) -> GraphIndex:
         """The lazily-built per-predicate CSR index over ``spo``."""
         if self._index is None:
-            self._index = GraphIndex(self._spo, self.TYPE, self.INSTANCE_OF)
+            with span("store.index"):
+                self._index = GraphIndex(self._spo, self.TYPE,
+                                         self.INSTANCE_OF)
         return self._index
 
     # -- construction ------------------------------------------------------

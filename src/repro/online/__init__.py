@@ -15,7 +15,8 @@ patterns are re-detected as the graph changes.  This package keeps a
   worth re-detecting;
 * :mod:`~repro.online.metrics` -- accumulator channels (per-batch value
   + running summary) for queue depth, batch/recompaction latency,
-  per-class savings, swap count;
+  per-class savings, swap count; and the program's spans (``span``,
+  ``SPANS``), which every layer from the store up records;
 * :mod:`~repro.online.service` -- the single-writer loop tying them
   together: drain a batch, swap the successor snapshot atomically,
   re-detect ONLY the drifted classes through the candidate-batched
@@ -24,10 +25,26 @@ patterns are re-detected as the graph changes.  This package keeps a
 
 Readers (``repro.serving.GraphQueryService``) hold the service's
 snapshot handle and never block on any of this.
+
+Exports resolve on first use: ``repro.core`` imports
+:mod:`repro.online.metrics` for its spans, and the package's other
+modules import ``repro.core`` in turn.
 """
-from .drift import DriftTracker  # noqa: F401
-from .metrics import Channel, MetricsHub  # noqa: F401
-from .recovery import (RecoveryError, RecoveryReport,  # noqa: F401
-                       SnapshotCheckpointer, recover)
-from .service import BatchReport, OnlineCompactionService  # noqa: F401
-from .wal import DurableWAL, IngestBatch, IngestQueue  # noqa: F401
+import importlib
+
+_EXPORTS = {
+    "DriftTracker": "drift",
+    "Channel": "metrics", "MetricsHub": "metrics",
+    "RecoveryError": "recovery", "RecoveryReport": "recovery",
+    "SnapshotCheckpointer": "recovery", "recover": "recovery",
+    "BatchReport": "service", "OnlineCompactionService": "service",
+    "DurableWAL": "wal", "IngestBatch": "wal", "IngestQueue": "wal",
+}
+__all__ = sorted(_EXPORTS)
+
+
+def __getattr__(name: str):
+    mod = _EXPORTS.get(name)
+    if mod is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    return getattr(importlib.import_module(f".{mod}", __name__), name)

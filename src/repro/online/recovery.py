@@ -42,6 +42,7 @@ import numpy as np
 from repro.api.snapshot import GraphSnapshot
 from repro.core.triples import TermDict
 
+from .metrics import span
 from .wal import DurableWAL, IngestBatch
 
 
@@ -257,76 +258,74 @@ def recover(root: str, *, wal_kwargs: dict | None = None,
     """
     from .service import OnlineCompactionService
 
-    t0 = time.perf_counter()
-    ck = SnapshotCheckpointer(ckpt_dir(root), keep=keep)
-    restored = ck.restore_latest()
-    if restored is None:
-        raise FileNotFoundError(f"no valid checkpoint under {root}")
-    d = restored.snapshot.store.dict
-    wal = DurableWAL(wal_dir(root), **(wal_kwargs or {}))
-    mints_replayed = 0
-    skipped = 0
-    pending: list[IngestBatch] = []
-    apply_runs: list[list[int]] = []
-    max_seq = restored.applied_seq
-    for kind, rec in wal.replay():
-        if kind == "mint":
-            for tid, term in rec:
-                if tid < len(d):
-                    if d.term(tid) != term:
+    with span("recovery.replay") as sp:
+        ck = SnapshotCheckpointer(ckpt_dir(root), keep=keep)
+        restored = ck.restore_latest()
+        if restored is None:
+            raise FileNotFoundError(f"no valid checkpoint under {root}")
+        d = restored.snapshot.store.dict
+        wal = DurableWAL(wal_dir(root), **(wal_kwargs or {}))
+        mints_replayed = 0
+        skipped = 0
+        pending: list[IngestBatch] = []
+        apply_runs: list[list[int]] = []
+        max_seq = restored.applied_seq
+        for kind, rec in wal.replay():
+            if kind == "mint":
+                for tid, term in rec:
+                    if tid < len(d):
+                        if d.term(tid) != term:
+                            raise RecoveryError(
+                                f"mint replay diverged at id {tid}: journal "
+                                f"{term!r} vs checkpoint {d.term(tid)!r}")
+                        continue
+                    got = d.id(term)
+                    if got != tid:
                         raise RecoveryError(
-                            f"mint replay diverged at id {tid}: journal "
-                            f"{term!r} vs checkpoint {d.term(tid)!r}")
-                    continue
-                got = d.id(term)
-                if got != tid:
-                    raise RecoveryError(
-                        f"mint replay out of order: {term!r} journaled "
-                        f"as {tid}, re-minted as {got}")
-                mints_replayed += 1
-        elif kind == "batch":
-            max_seq = max(max_seq, rec.seq)
-            if rec.seq > restored.applied_seq:
-                pending.append(rec)
-            else:
-                skipped += 1
-        else:                                   # "apply"
-            runs = [s for s in rec if s > restored.applied_seq]
-            if runs:
-                apply_runs.append(runs)
-    svc = OnlineCompactionService(
-        restored.snapshot, wal=wal, checkpointer=ck, **service_kwargs)
-    svc.drift.load_state(restored.drift)
-    svc.queue.restore(pending, next_seq=max_seq + 1)
-    svc._applied_seq = restored.applied_seq
-    # re-apply the suffix the pre-crash process had already committed,
-    # group by group; whatever remains queued was never applied anywhere
-    # and drains under normal coalescing
-    runs_replayed = 0
-    applied = restored.applied_seq
-    for run in apply_runs:
-        run = [s for s in run if s > applied]
-        if not run:
-            continue                # duplicate from a prior recovery
-        svc.apply_exact(run)
-        applied = run[-1]
-        runs_replayed += 1
-    report = RecoveryReport(
-        checkpoint_step=restored.step,
-        checkpoint_bytes=restored.nbytes,
-        applied_seq=restored.applied_seq,
-        n_terms_checkpoint=restored.n_terms,
-        mints_replayed=mints_replayed,
-        batches_pending=len(pending), batches_skipped=skipped,
-        apply_runs_replayed=runs_replayed,
-        truncated_bytes=wal.truncated_bytes,
-        dropped_segments=wal.dropped_segments,
-        replay_ms=(time.perf_counter() - t0) * 1e3)
+                            f"mint replay out of order: {term!r} journaled "
+                            f"as {tid}, re-minted as {got}")
+                    mints_replayed += 1
+            elif kind == "batch":
+                max_seq = max(max_seq, rec.seq)
+                if rec.seq > restored.applied_seq:
+                    pending.append(rec)
+                else:
+                    skipped += 1
+            else:                                   # "apply"
+                runs = [s for s in rec if s > restored.applied_seq]
+                if runs:
+                    apply_runs.append(runs)
+        svc = OnlineCompactionService(
+            restored.snapshot, wal=wal, checkpointer=ck, **service_kwargs)
+        svc.drift.load_state(restored.drift)
+        svc.queue.restore(pending, next_seq=max_seq + 1)
+        svc._applied_seq = restored.applied_seq
+        # re-apply the suffix the pre-crash process had already committed,
+        # group by group; whatever remains queued was never applied anywhere
+        # and drains under normal coalescing
+        runs_replayed = 0
+        applied = restored.applied_seq
+        for run in apply_runs:
+            run = [s for s in run if s > applied]
+            if not run:
+                continue                # duplicate from a prior recovery
+            svc.apply_exact(run)
+            applied = run[-1]
+            runs_replayed += 1
+        report = RecoveryReport(
+            checkpoint_step=restored.step,
+            checkpoint_bytes=restored.nbytes,
+            applied_seq=restored.applied_seq,
+            n_terms_checkpoint=restored.n_terms,
+            mints_replayed=mints_replayed,
+            batches_pending=len(pending), batches_skipped=skipped,
+            apply_runs_replayed=runs_replayed,
+            truncated_bytes=wal.truncated_bytes,
+            dropped_segments=wal.dropped_segments,
+            replay_ms=sp.ms)
     svc.last_recovery = report
     svc.metrics.observe("recovery.checkpoint_bytes",
                         report.checkpoint_bytes)
-    svc.metrics.observe("recovery.replay_ms", report.replay_ms)
     svc.metrics.observe("recovery.batches_replayed",
                         report.batches_pending)
-    svc.metrics.observe("recovery.mints_replayed", report.mints_replayed)
     return svc

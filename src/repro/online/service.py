@@ -43,9 +43,16 @@ freshness.
 Every step feeds the accumulator metrics channels (``queue.depth``,
 ``ingest.batch_ms``, ``ingest.unknown_deletes``, ``redetect.ms``,
 ``redetect.dirty_classes``, ``swap.count``, ``checkpoint.bytes``,
-``savings.<class>``, ...): per-batch last value plus running
-summaries, exported by :meth:`metrics_summary` and
-``launch/serve.py --online``.
+``checkpoint.failures``, ``savings.<class>``, ...): per-batch last
+value plus running summaries, exported by :meth:`metrics_summary` and
+``launch/serve.py --online``.  Its stages are spans
+(:func:`~repro.online.metrics.span`, keyed by batch seq):
+``service.submit``, ``queue.wait`` (a batch's ack to the start of the
+step that applies it), ``service.step``, ``service.apply``,
+``service.swap``, ``service.redetect``, ``service.recompress``,
+``checkpoint.join`` (the writer blocked on the previous checkpoint)
+and ``checkpoint.write`` (the background writer, caused by the step
+that started it).
 """
 from __future__ import annotations
 
@@ -63,7 +70,7 @@ from repro.core.triples import TripleStore
 from repro.dist import fault
 
 from .drift import DriftTracker
-from .metrics import MetricsHub
+from .metrics import SPANS, MetricsHub, span
 from .wal import DurableWAL, IngestBatch, IngestQueue
 
 
@@ -176,6 +183,9 @@ class OnlineCompactionService:
         self._since_checkpoint = 0
         self._ckpt_thread: threading.Thread | None = None
         self._ckpt_error: BaseException | None = None
+        # perf_counter_ns at which each queued batch was acknowledged,
+        # popped when its apply starts (the ``queue.wait`` span)
+        self._acked_ns: dict[int, int] = {}
         # every dict id below this is journaled (or checkpoint-covered);
         # construction mints (initial compaction) are covered by the
         # initial checkpoint ``durable()`` writes, never by the WAL
@@ -268,8 +278,8 @@ class OnlineCompactionService:
         self._since_checkpoint = 0
         if self.checkpoint_async:
             self._ckpt_thread = threading.Thread(
-                target=self._write_checkpoint, args=(*args, False),
-                daemon=True)
+                target=self._write_checkpoint,
+                args=(*args, False, SPANS.current()), daemon=True)
             self._ckpt_thread.start()
             if wait:
                 self._join_checkpoint()
@@ -277,18 +287,16 @@ class OnlineCompactionService:
             self._write_checkpoint(*args, True)
 
     def _write_checkpoint(self, snap, applied_seq, n_terms, drift_state,
-                          reraise) -> None:
+                          reraise, cause=None) -> None:
         try:
-            path = self.checkpointer.write(
-                snapshot=snap, applied_seq=applied_seq, n_terms=n_terms,
-                drift=drift_state, fire=self._fire)
-            from .recovery import _dir_bytes
-            self.metrics.observe("checkpoint.bytes", _dir_bytes(path))
-            self.metrics.observe("checkpoint.count", 1)
-            if self.wal is not None:
-                removed = self.wal.gc(applied_seq, n_terms)
-                if removed:
-                    self.metrics.observe("wal.segments_gcd", removed)
+            with span("checkpoint.write", key=applied_seq, cause=cause):
+                path = self.checkpointer.write(
+                    snapshot=snap, applied_seq=applied_seq,
+                    n_terms=n_terms, drift=drift_state, fire=self._fire)
+                from .recovery import _dir_bytes
+                self.metrics.observe("checkpoint.bytes", _dir_bytes(path))
+                if self.wal is not None:
+                    self.wal.gc(applied_seq, n_terms)
         except BaseException as e:
             self.metrics.observe("checkpoint.failures", 1)
             if reraise:
@@ -298,7 +306,8 @@ class OnlineCompactionService:
     def _join_checkpoint(self) -> None:
         t = self._ckpt_thread
         if t is not None:
-            t.join()
+            with span("checkpoint.join", key=self._applied_seq):
+                t.join()
             self._ckpt_thread = None
         err, self._ckpt_error = self._ckpt_error, None
         if err is not None:
@@ -326,6 +335,14 @@ class OnlineCompactionService:
         ``submit`` returns; a crash at the ``wal.append`` site means
         the batch was never accepted (the caller re-submits).
         """
+        with span("service.submit") as sp:
+            batch = self._submit(inserts, delete_triples, delete_entities)
+            sp.key = batch.seq
+        self._acked_ns[batch.seq] = sp.end_ns
+        return batch
+
+    def _submit(self, inserts, delete_triples, delete_entities
+                ) -> IngestBatch:
         self._fire("wal.append")
         d = self._snapshot.store.dict
         unknown = 0
@@ -364,11 +381,12 @@ class OnlineCompactionService:
         self.metrics.observe("queue.depth", self.queue.depth)
         return batch
 
-    def _swap(self, snap: GraphSnapshot) -> None:
-        self._snapshot = snap          # the atomic commit
-        with self._swap_lock:
-            self.swap_count += 1
-        self.metrics.observe("swap.count", self.swap_count)
+    def _swap(self, snap: GraphSnapshot, seq: int) -> None:
+        with span("service.swap", key=seq):
+            self._snapshot = snap          # the atomic commit
+            with self._swap_lock:
+                self.swap_count += 1
+            self.metrics.observe("swap.count", self.swap_count)
 
     def step(self) -> BatchReport | None:
         """Apply the head batch -- or, with coalescing on, the maximal
@@ -376,14 +394,21 @@ class OnlineCompactionService:
         delete-carrying batch) merged into ONE apply: build the
         successor snapshot, swap, commit the run, then re-detect
         drifted classes."""
-        if self.coalesce:
-            batches = self.queue.peek_coalesced(self.max_coalesce)
-        else:
-            head = self.queue.peek()
-            batches = [head] if head is not None else []
-        if not batches:
-            return None
-        return self._apply_run(batches)
+        with span("service.step") as sp:
+            if self.coalesce:
+                batches = self.queue.peek_coalesced(self.max_coalesce)
+            else:
+                head = self.queue.peek()
+                batches = [head] if head is not None else []
+            if not batches:
+                return None
+            sp.key = batches[-1].seq
+            for b in batches:
+                acked = self._acked_ns.pop(b.seq, None)
+                if acked is not None:
+                    SPANS.record("queue.wait", acked, sp.start_ns,
+                                 key=b.seq)
+            return self._apply_run(batches)
 
     def apply_exact(self, seqs) -> BatchReport:
         """Apply EXACTLY the head run ``seqs`` as one merged step.
@@ -401,7 +426,36 @@ class OnlineCompactionService:
         return self._apply_run(head[:len(want)])
 
     def _apply_run(self, batches: list[IngestBatch]) -> BatchReport:
-        t0 = time.perf_counter()
+        last = batches[-1]
+        with span("service.apply", key=last.seq, hub=self.metrics,
+                  channel="ingest.batch_ms") as sp:
+            epoch_before, upd, dele = self._apply_commit(batches)
+        latency = sp.ms
+        self.metrics.observe("queue.depth", self.queue.depth)
+        red = None
+        if self.auto_redetect:
+            dirty = self.drift.dirty_classes(self._snapshot.fgraph)
+            if dirty:
+                red = self.redetect(dirty)
+        self._plain_tail += sum(int(b.inserts.shape[0]) for b in batches)
+        self._maybe_recompress()
+        # checkpoint LAST: a checkpoint between commit and this step's
+        # re-detection would restore to a state whose redetect never
+        # re-runs (the batch is already inside the checkpoint), silently
+        # diverging from the uninterrupted run's mint order
+        if self.checkpointer is not None:
+            self._since_checkpoint += len(batches)
+            if self._since_checkpoint >= self.checkpoint_every:
+                self.checkpoint()
+        return BatchReport(seq=last.seq, epoch_before=epoch_before,
+                           epoch_after=self._snapshot.epoch,
+                           latency_ms=latency, update=upd, delete=dele,
+                           redetect=red)
+
+    def _apply_commit(self, batches: list[IngestBatch]):
+        """Build the run's successor, swap, journal and commit it, and
+        feed the drift counters: ``(epoch before, update report,
+        delete report)``."""
         snap = self._snapshot
         epoch_before = snap.epoch
         # Merge the run: inserts concatenate in FIFO order; only the
@@ -425,7 +479,7 @@ class OnlineCompactionService:
         self._log_mints()                  # surrogate mints, pre-swap
         self._fire("pre_swap")
         if snap is not self._snapshot:
-            self._swap(snap)
+            self._swap(snap, last.seq)
         self._fire("post_swap")
         if self.wal is not None:
             self.wal.append_applied([b.seq for b in batches])
@@ -437,28 +491,7 @@ class OnlineCompactionService:
             self.drift.observe_update(upd)
         if dele is not None:
             self.drift.observe_delete(dele.stats)
-        latency = (time.perf_counter() - t0) * 1e3
-        self.metrics.observe("ingest.batch_ms", latency)
-        self.metrics.observe("queue.depth", self.queue.depth)
-        red = None
-        if self.auto_redetect:
-            dirty = self.drift.dirty_classes(self._snapshot.fgraph)
-            if dirty:
-                red = self.redetect(dirty)
-        self._plain_tail += int(inserts.shape[0])
-        self._maybe_recompress()
-        # checkpoint LAST: a checkpoint between commit and this step's
-        # re-detection would restore to a state whose redetect never
-        # re-runs (the batch is already inside the checkpoint), silently
-        # diverging from the uninterrupted run's mint order
-        if self.checkpointer is not None:
-            self._since_checkpoint += len(batches)
-            if self._since_checkpoint >= self.checkpoint_every:
-                self.checkpoint()
-        return BatchReport(seq=last.seq, epoch_before=epoch_before,
-                           epoch_after=self._snapshot.epoch,
-                           latency_ms=latency, update=upd, delete=dele,
-                           redetect=red)
+        return epoch_before, upd, dele
 
     def _maybe_recompress(self) -> None:
         """Re-pack the plain mutable tail once it outgrows the
@@ -475,13 +508,13 @@ class OnlineCompactionService:
         if getattr(store, "is_compressed", False):
             self._plain_tail = 0
             return
-        t0 = time.perf_counter()
-        packed = store.compressed(compact_dict=False)
-        self._swap(snap.next(snap.fgraph.with_store(packed)))
+        with span("service.recompress", key=self._applied_seq,
+                  hub=self.metrics, channel="ingest.recompress_ms"):
+            packed = store.compressed(compact_dict=False)
+            self._swap(snap.next(snap.fgraph.with_store(packed)),
+                       self._applied_seq)
         self._plain_tail = 0
         self.metrics.observe("ingest.recompressions", 1)
-        self.metrics.observe("ingest.recompress_ms",
-                             (time.perf_counter() - t0) * 1e3)
 
     def drain(self, max_batches: int | None = None) -> list[BatchReport]:
         """Apply queued batches FIFO until empty (or ``max_batches``)."""
@@ -509,7 +542,10 @@ class OnlineCompactionService:
         cids = [int(c) for c in class_ids]
         if not cids:
             return None
+        with span("service.redetect", key=self._applied_seq):
+            return self._redetect(cids)
 
+    def _redetect(self, cids: list[int]) -> RedetectReport | None:
         def attempt():
             self._fire("redetect")
             self._redetect_step += 1
@@ -536,7 +572,7 @@ class OnlineCompactionService:
             return None
         if snap is not self._snapshot:     # rejected passes don't swap
             self._log_mints()
-            self._swap(snap)
+            self._swap(snap, self._applied_seq)
         # re-baseline either way: the decision was made against this
         # state; drift re-accumulates before the classes go dirty again
         # -- but a rejected pass also bumps the classes' backoff, so

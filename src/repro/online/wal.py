@@ -41,6 +41,8 @@ from typing import Iterable, Iterator
 
 import numpy as np
 
+from .metrics import span
+
 _EMPTY3 = np.empty((0, 3), np.int32)
 _EMPTY1 = np.empty((0,), np.int64)
 
@@ -306,7 +308,9 @@ class DurableWAL:
 
     The appender is single-threaded (the service's writer loop) but
     :meth:`gc` may run from the checkpoint writer thread, hence the
-    lock around segment bookkeeping.
+    lock around segment bookkeeping.  Each record's write and flush is
+    a ``wal.append`` span and each fsync a ``wal.fsync`` span, keyed by
+    the batch seq where there is one.
     """
 
     def __init__(self, root: str, *, fsync_policy: str = "every_batch",
@@ -366,7 +370,7 @@ class DurableWAL:
         if self._fh.tell() < self.segment_max_bytes:
             return
         self._fh.flush()
-        os.fsync(self._fh.fileno())
+        self._fsync()
         self._fh.close()
         last = os.path.basename(self._segments[-1])
         n = int(last[4:12]) + 1
@@ -377,11 +381,18 @@ class DurableWAL:
         self._fh = open(path, "ab")
         self._fh.write(WAL_MAGIC)
 
-    def _append(self, rec_type: int, payload: bytes) -> None:
-        self._maybe_rotate()
-        self._fh.write(_frame(rec_type, payload))
-        with self._lock:
-            self._stats[self._segments[-1]].note(rec_type, payload)
+    def _append(self, rec_type: int, payload: bytes, key=None) -> None:
+        """Write and flush one record (no fsync)."""
+        with span("wal.append", key=key):
+            self._maybe_rotate()
+            self._fh.write(_frame(rec_type, payload))
+            with self._lock:
+                self._stats[self._segments[-1]].note(rec_type, payload)
+            self._fh.flush()
+
+    def _fsync(self, key=None) -> None:
+        with span("wal.fsync", key=key):
+            os.fsync(self._fh.fileno())
 
     def append_mints(self, mints: list[tuple[int, str]]) -> None:
         """Journal dictionary-tail mints, in allocation order.  Must be
@@ -390,16 +401,12 @@ class DurableWAL:
         if not mints:
             return
         self._append(REC_MINT, _encode_mints(mints))
-        self._fh.flush()
 
     def append_batch(self, batch: IngestBatch) -> None:
-        self._append(REC_BATCH, _encode_batch(batch))
-        self._fh.flush()
-        if self.fsync_policy == "every_batch":
-            os.fsync(self._fh.fileno())
-            self._last_sync = self._clock()
-        elif self._clock() - self._last_sync >= self.fsync_interval_s:
-            os.fsync(self._fh.fileno())
+        self._append(REC_BATCH, _encode_batch(batch), key=batch.seq)
+        if self.fsync_policy == "every_batch" or \
+                self._clock() - self._last_sync >= self.fsync_interval_s:
+            self._fsync(batch.seq)
             self._last_sync = self._clock()
 
     def append_applied(self, seqs: list[int]) -> None:
@@ -409,12 +416,13 @@ class DurableWAL:
         with it re-detection decisions and mint order, so replaying a
         suffix under a different grouping would diverge from the
         uninterrupted run's id assignment."""
-        self._append(REC_APPLY, _encode_apply(list(seqs)))
-        self._fh.flush()
+        seqs = list(seqs)
+        self._append(REC_APPLY, _encode_apply(seqs),
+                     key=seqs[-1] if seqs else None)
 
     def sync(self) -> None:
         self._fh.flush()
-        os.fsync(self._fh.fileno())
+        self._fsync()
         self._last_sync = self._clock()
 
     # -- replay ------------------------------------------------------------
@@ -474,5 +482,5 @@ class DurableWAL:
     def close(self) -> None:
         if not self._fh.closed:
             self._fh.flush()
-            os.fsync(self._fh.fileno())
+            self._fsync()
             self._fh.close()

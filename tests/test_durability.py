@@ -11,6 +11,7 @@ import os
 import signal
 import subprocess
 import sys
+import tempfile
 
 import numpy as np
 import pytest
@@ -155,10 +156,15 @@ def _reference_journal(tmp_path):
 @settings(max_examples=40)
 @given(cut=st.integers(min_value=0, max_value=400),
        corrupt=st.booleans(), flip=st.integers(min_value=1, max_value=255))
-def test_wal_torn_tail_recovers_longest_valid_prefix(tmp_path, cut,
-                                                     corrupt, flip):
-    sub = tmp_path / f"c{cut}_{int(corrupt)}_{flip}"
-    os.makedirs(sub)
+def test_wal_torn_tail_recovers_longest_valid_prefix(cut, corrupt, flip):
+    # a directory per generated example: hypothesis runs every example
+    # inside one call of the test, so a function-scoped ``tmp_path``
+    # would be shared by all of them
+    with tempfile.TemporaryDirectory() as sub:
+        _torn_tail_case(sub, cut, corrupt, flip)
+
+
+def _torn_tail_case(sub, cut, corrupt, flip):
     path, data, full_kinds = _reference_journal(sub)
     cut = min(cut, len(data))
     if corrupt and cut < len(data):
@@ -342,11 +348,12 @@ def test_crash_at_every_site_recovers_with_digest_parity(tmp_path, site):
 
 @settings(max_examples=6)
 @given(site=st.sampled_from(SITES), occurrence=st.integers(0, 1))
-def test_crash_recovery_parity_property(tmp_path, site, occurrence):
-    sub = tmp_path / f"{site.replace('.', '_')}_{occurrence}"
+def test_crash_recovery_parity_property(site, occurrence):
     seq = _novel_batches(_store(), 6)
     want = _reference_digest(seq)
-    svc, _ = _crash_run(sub, seq, site, occurrence)
+    with tempfile.TemporaryDirectory() as sub:
+        svc, _ = _crash_run(os.path.join(sub, "root"), seq, site,
+                            occurrence)
     assert svc.queue.depth == 0
     assert svc.snapshot.digest() == want
 
