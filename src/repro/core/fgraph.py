@@ -39,7 +39,7 @@ import numpy as np
 
 from repro.online.metrics import span
 
-from .index import SPO_PERM, _key_view, csr_take, in_sorted, sort_unique
+from .index import SPO_PERM, csr_take, in_sorted, rows_in_sorted, sort_unique
 from .star import num_edges
 from .triples import TripleStore
 
@@ -516,9 +516,7 @@ class FactorizedGraph:
         self._check_semantic_rows(rows)
         stats = DeleteStats(n_requested=int(rows.shape[0]))
         store = self.store
-        present = in_sorted(_key_view(rows, SPO_PERM),
-                            _key_view(store.spo, SPO_PERM)) \
-            if store.spo.shape[0] else np.zeros(rows.shape[0], bool)
+        present = rows_in_sorted(rows, store.spo, SPO_PERM)
         raw_del = rows[present]
         stats.n_raw_removed = int(raw_del.shape[0])
         # molecule-covered deletions: (entity, surrogate) -> dissolved arms
@@ -640,8 +638,7 @@ class FactorizedGraph:
         spo = self.store.spo
         if removed_rows is not None and removed_rows.shape[0]:
             dr = sort_unique(removed_rows, SPO_PERM)
-            keep = ~in_sorted(_key_view(spo, SPO_PERM),
-                              _key_view(dr, SPO_PERM))
+            keep = ~rows_in_sorted(spo, dr, SPO_PERM)
             spo = spo[keep]
         out = TripleStore.from_ids(self.store.dict, spo, presorted=True)
         if added:

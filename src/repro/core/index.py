@@ -37,29 +37,109 @@ import numpy as np
 SPO_PERM = (0, 1, 2)      # TripleStore.spo canonical order
 PSO_PERM = (1, 0, 2)      # GraphIndex row order
 
+# Row keys.  Sorting and binary search compare rows as one key.  Where
+# every id is >= 0 and fits _PACK_MAX_BITS bits, the key is the three
+# columns packed into one uint64, (c0 << 2b) | (c1 << b) | c2 in ``perm``
+# order, whose unsigned order is the rows' lexicographic order; NumPy
+# sorts and searches it natively.  Wider ids take an exact fallback:
+# ``np.lexsort`` for sorts and a structured (void) key for searches,
+# both compared through a generic per-element callback, an order of
+# magnitude slower.  Both sides of a search are keyed at one shared width.
+_PACK_MAX_BITS = 21       # 3 * 21 = 63 bits
 _KEY_DTYPE = np.dtype([("a", np.int32), ("b", np.int32), ("c", np.int32)])
+
+# keyed sorts and searches by path: the store's engagement counter
+KEY_STATS = {"packed": 0, "wide": 0}
+
+
+def reset_key_stats() -> None:
+    KEY_STATS["packed"] = 0
+    KEY_STATS["wide"] = 0
+
+
+def _pack_width(arrays) -> int | None:
+    """Shared bit width of packed keys over every id of ``arrays``, or
+    None (the wide path) if an id is negative or needs more than
+    ``_PACK_MAX_BITS`` bits.  Counts the keyed operation in KEY_STATS."""
+    lo = hi = 0
+    for a in arrays:
+        if a.size:
+            lo = min(lo, int(a.min()))
+            hi = max(hi, int(a.max()))
+    if lo < 0 or hi >> _PACK_MAX_BITS:
+        KEY_STATS["wide"] += 1
+        return None
+    KEY_STATS["packed"] += 1
+    return max(hi.bit_length(), 1)
+
+
+def _pack(rows: np.ndarray, perm, b: int) -> np.ndarray:
+    key = rows[:, perm[0]].astype(np.uint64) << np.uint64(2 * b)
+    key |= rows[:, perm[1]].astype(np.uint64) << np.uint64(b)
+    key |= rows[:, perm[2]].astype(np.uint64)
+    return key
+
+
+def _unpack(key: np.ndarray, perm, b: int) -> np.ndarray:
+    rows = np.empty((key.shape[0], 3), np.int32)
+    mask = np.uint64((1 << b) - 1)
+    rows[:, perm[0]] = key >> np.uint64(2 * b)
+    rows[:, perm[1]] = (key >> np.uint64(b)) & mask
+    rows[:, perm[2]] = key & mask
+    return rows
 
 
 def _key_view(rows: np.ndarray, perm) -> np.ndarray:
     """Structured (void) view of (n, 3) int32 rows under column order
-    ``perm`` -- lexicographically comparable/searchable as one key."""
+    ``perm`` -- the wide path's searchable key."""
     arr = np.ascontiguousarray(rows[:, list(perm)], dtype=np.int32)
     return arr.view(_KEY_DTYPE).ravel()
+
+
+def _sorted(rows: np.ndarray, perm, unique: bool) -> np.ndarray:
+    rows = np.ascontiguousarray(rows, dtype=np.int32).reshape(-1, 3)
+    if rows.shape[0] <= 1:
+        return rows
+    b = _pack_width((rows,))
+    if b is not None:
+        key = _pack(rows, perm, b)
+        key.sort()
+        if unique:
+            key = key[np.append(True, key[1:] != key[:-1])]
+        return _unpack(key, perm, b)
+    rows = rows[np.lexsort((rows[:, perm[2]], rows[:, perm[1]],
+                            rows[:, perm[0]]))]
+    if unique:
+        rows = rows[np.append(True, np.any(rows[1:] != rows[:-1], axis=1))]
+    return rows
+
+
+def sort_rows(rows: np.ndarray, perm) -> np.ndarray:
+    """Sort (n, 3) rows by the ``perm`` column order, duplicates kept."""
+    return _sorted(rows, perm, unique=False)
 
 
 def sort_unique(rows: np.ndarray, perm=SPO_PERM) -> np.ndarray:
     """Sort (n, 3) rows by the ``perm`` column order and drop duplicates.
     Unlike ``np.unique(axis=0)`` the key order is configurable."""
-    rows = np.ascontiguousarray(rows, dtype=np.int32).reshape(-1, 3)
-    if rows.shape[0] <= 1:
-        return rows
-    key = _key_view(rows, perm)
-    order = np.argsort(key, kind="stable")
-    rows = rows[order]
-    keep = np.empty(rows.shape[0], bool)
-    keep[0] = True
-    np.any(rows[1:] != rows[:-1], axis=1, out=keep[1:])
-    return rows[keep]
+    return _sorted(rows, perm, unique=True)
+
+
+def _row_keys(perm, *arrays) -> list[np.ndarray]:
+    """Searchable keys of each (n, 3) row array, all at one encoding."""
+    b = _pack_width(arrays)
+    if b is not None:
+        return [_pack(a, perm, b) for a in arrays]
+    return [_key_view(a, perm) for a in arrays]
+
+
+def rows_in_sorted(rows: np.ndarray, sorted_ref: np.ndarray, perm=SPO_PERM
+                   ) -> np.ndarray:
+    """Membership of each row of ``rows`` in ``sorted_ref`` (sorted-unique
+    under ``perm``) via binary search on the row key."""
+    if rows.shape[0] == 0 or sorted_ref.shape[0] == 0:
+        return np.zeros(rows.shape[0], bool)
+    return in_sorted(*_row_keys(perm, rows, sorted_ref))
 
 
 def setdiff_rows(new: np.ndarray, old: np.ndarray, perm=SPO_PERM
@@ -68,7 +148,7 @@ def setdiff_rows(new: np.ndarray, old: np.ndarray, perm=SPO_PERM
     ``perm``); order of ``new`` preserved.  O(m log n)."""
     if new.shape[0] == 0 or old.shape[0] == 0:
         return new
-    return new[~in_sorted(_key_view(new, perm), _key_view(old, perm))]
+    return new[~rows_in_sorted(new, old, perm)]
 
 
 def merge_disjoint(old: np.ndarray, new: np.ndarray, perm=SPO_PERM
@@ -80,7 +160,7 @@ def merge_disjoint(old: np.ndarray, new: np.ndarray, perm=SPO_PERM
         return old
     if old.shape[0] == 0:
         return new
-    pos = np.searchsorted(_key_view(old, perm), _key_view(new, perm))
+    pos = np.searchsorted(*_row_keys(perm, old, new))
     out = np.empty((old.shape[0] + new.shape[0], 3), np.int32)
     new_at = pos + np.arange(new.shape[0])
     old_mask = np.ones(out.shape[0], bool)
@@ -125,9 +205,8 @@ class GraphIndex:
     def __init__(self, spo: np.ndarray, type_id: int, instance_of_id: int,
                  *, _presorted: bool = False) -> None:
         rows = np.ascontiguousarray(spo, dtype=np.int32).reshape(-1, 3)
-        if not _presorted and rows.shape[0] > 1:
-            order = np.argsort(_key_view(rows, PSO_PERM), kind="stable")
-            rows = rows[order]
+        if not _presorted:
+            rows = sort_rows(rows, PSO_PERM)
         self.rows = rows
         self.type_id = int(type_id)
         self.instance_of_id = int(instance_of_id)

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.online.metrics import span
 
+from .index import reset_key_stats
 from .star import StarSweepResult, ami, num_edges, num_edges_batch
 from .triples import TripleStore
 
@@ -116,6 +117,9 @@ def register_stats_reset(fn) -> None:
     :func:`reset_trace_stats` (idempotent per function)."""
     if fn not in _EXTRA_STAT_RESETS:
         _EXTRA_STAT_RESETS.append(fn)
+
+
+register_stats_reset(reset_key_stats)
 
 
 def reset_trace_stats() -> None:

@@ -3,9 +3,12 @@ dedup skipping on provably-sorted paths, and the TermDict dtype contract."""
 import numpy as np
 import pytest
 
+from repro.api import Compactor
+from repro.core import sweep as core_sweep
 from repro.core import triples as triples_mod
-from repro.core.index import (GraphIndex, PSO_PERM, SPO_PERM, in_sorted,
-                              merge_disjoint, setdiff_rows, sort_unique)
+from repro.core.index import (GraphIndex, KEY_STATS, PSO_PERM, SPO_PERM,
+                              in_sorted, merge_disjoint, rows_in_sorted,
+                              setdiff_rows, sort_unique)
 from repro.core.triples import TermDict, TripleStore
 from repro.data.synthetic import SensorGraphSpec, generate
 
@@ -135,6 +138,131 @@ def test_copy_shares_index_and_diverges_on_append():
     clone.add_ids(np.array([[5, store.TYPE, 7]], np.int32))
     assert clone._index is not store._index
     assert store.n_triples == clone.n_triples - 1
+
+
+# ---------------------------------------------------------------------------
+# row keys: the packed uint64 key and its wide fallback are byte-identical
+# to a structured-key reference at every id width
+# ---------------------------------------------------------------------------
+
+_REF_DTYPE = np.dtype([("a", np.int32), ("b", np.int32), ("c", np.int32)])
+
+
+def _ref_key(rows, perm):
+    arr = np.ascontiguousarray(rows[:, list(perm)], dtype=np.int32)
+    return arr.view(_REF_DTYPE).ravel()
+
+
+def _ref_sort(rows, perm, unique):
+    rows = np.asarray(rows, np.int32).reshape(-1, 3)
+    rows = rows[np.argsort(_ref_key(rows, perm), kind="stable")]
+    if unique and rows.shape[0]:
+        keep = np.append(True, np.any(rows[1:] != rows[:-1], axis=1))
+        rows = rows[keep]
+    return rows
+
+
+def _ref_in(rows, ref, perm):
+    return np.isin(_ref_key(rows, perm), _ref_key(ref, perm))
+
+
+def _ref_merge(old, new, perm):
+    return _ref_sort(np.concatenate([old, new]), perm, unique=True)
+
+
+_WIDTHS = {"narrow": (0, 2**19 - 1), "boundary": (0, 2**21 - 1),
+           "wide": (0, 2**31 - 1), "negative": (-2**20, 2**20)}
+
+
+def _rows_at(rng, width, n, pool=24):
+    """Rows over a small pool of ids spanning ``width``, both ends
+    included, so duplicates and shared prefixes occur."""
+    lo, hi = _WIDTHS[width]
+    ids = np.concatenate([[lo, hi], rng.integers(lo, hi, pool - 2)])
+    return ids[rng.integers(0, pool, (n, 3))].astype(np.int32)
+
+
+def _case_rows(rng, width, shape, perm):
+    if shape == "tiny":
+        return [_rows_at(rng, width, 0), _rows_at(rng, width, 1)]
+    rows = _rows_at(rng, width, 400)
+    if shape == "duplicates":
+        rows = np.concatenate([rows, rows[::3]])
+        return [rows[rng.permutation(rows.shape[0])]]
+    return [_ref_sort(rows, perm, unique=True)]           # already sorted
+
+
+def _assert_same(got, want):
+    assert got.dtype == np.int32 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("shape", ["duplicates", "sorted", "tiny"])
+@pytest.mark.parametrize("width", ["narrow", "boundary", "wide", "negative"])
+@pytest.mark.parametrize("perm", [SPO_PERM, PSO_PERM], ids=["spo", "pso"])
+def test_row_keys_match_structured_reference(perm, width, shape):
+    rng = np.random.default_rng(sum(map(ord, width + shape)) + perm[0])
+    other = _rows_at(rng, width, 300)
+    old = _ref_sort(other, perm, unique=True)
+    for rows in _case_rows(rng, width, shape, perm):
+        new = _ref_sort(rows, perm, unique=True)
+        _assert_same(sort_unique(rows, perm), new)
+        np.testing.assert_array_equal(rows_in_sorted(new, old, perm),
+                                      _ref_in(new, old, perm))
+        for a, b in ((new, old), (old, new)):
+            fresh = setdiff_rows(a, b, perm)
+            _assert_same(fresh, a[~_ref_in(a, b, perm)])
+            _assert_same(merge_disjoint(b, fresh, perm),
+                         _ref_merge(b, a, perm))
+        # the index's (p, s, o) order keeps duplicates; merged dedups
+        _assert_same(GraphIndex(rows, 0, 1).rows,
+                     _ref_sort(rows, PSO_PERM, unique=False))
+        _assert_same(GraphIndex(old, 0, 1).merged(rows).rows,
+                     _ref_merge(old, rows, PSO_PERM))
+
+
+@pytest.mark.parametrize("old_hi", [2**19 - 1, 2**31 - 1],
+                         ids=["packed", "wide"])
+@pytest.mark.parametrize("perm", [SPO_PERM, PSO_PERM], ids=["spo", "pso"])
+def test_row_keys_share_one_width_across_both_sides(perm, old_hi):
+    """``new`` alone would pack at 5 bits; ``old`` needs 19 bits or the
+    wide path.  Keyed apart, equal rows would get unequal keys."""
+    rng = np.random.default_rng(old_hi % 97)
+    new = sort_unique(rng.integers(0, 32, (200, 3)).astype(np.int32), perm)
+    far = np.array([[old_hi, 3, 4], [5, old_hi, 6], [7, 8, old_hi]],
+                   np.int32)
+    old = _ref_sort(np.concatenate([new[::2], far]), perm, unique=True)
+    before = dict(KEY_STATS)
+    _assert_same(setdiff_rows(new, old, perm), new[1::2])
+    _assert_same(merge_disjoint(old, new[1::2], perm),
+                 _ref_merge(old, new, perm))
+    assert rows_in_sorted(new, old, perm).tolist() == \
+        [i % 2 == 0 for i in range(new.shape[0])]
+    path = "packed" if old_hi < 2**21 else "wide"
+    assert KEY_STATS[path] - before[path] == 3
+
+
+def test_key_stats_count_paths_and_reset():
+    """Ids in the benchmark's range (402,629 terms, under 2^19) take only
+    the packed path, through a whole compaction; one id at 2^21 takes
+    the wide path; ``reset_trace_stats`` zeroes both."""
+    core_sweep.reset_trace_stats()
+    assert KEY_STATS == {"packed": 0, "wide": 0}
+    store = _random_store(seed=4, n=200)
+    d = store.dict
+    ents = d.ids([f"ent/pad/{i}" for i in range(402_629 - len(d))])
+    assert int(ents[-1]) == 402_628
+    c0 = int(store.classes()[0])
+    store.add_ids(np.stack([ents[-50:], np.full(50, store.TYPE, np.int32),
+                            np.full(50, c0, np.int32)], axis=1))
+    Compactor(detector="gfsp", backend="host").run(store)
+    assert KEY_STATS["packed"] > 0 and KEY_STATS["wide"] == 0
+    wide = TripleStore.from_ids(
+        store.dict, np.concatenate([store.spo, [[2**21, 0, 0]]]))
+    assert wide.index.rows.shape[0] == store.n_triples + 1
+    assert KEY_STATS["wide"] == 2        # the (s, p, o) and (p, s, o) sorts
+    core_sweep.reset_trace_stats()
+    assert KEY_STATS == {"packed": 0, "wide": 0}
 
 
 # ---------------------------------------------------------------------------
