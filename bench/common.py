@@ -1,6 +1,7 @@
 """Pieces the drivers share: the graph, the store, arrivals, tails."""
 from __future__ import annotations
 
+import functools
 import time
 
 import numpy as np
@@ -27,9 +28,28 @@ def load_store(g: SensorGraph):
     return TripleStore.from_ids(TermDict.from_terms(g.terms), g.spo)
 
 
+# the chips of the run's cell; ``bench/run.py`` sets them before set-up
+DEVICES: list = []
+
+
+@functools.cache
+def _mesh(devices: tuple):
+    """The ``("data", "model")`` mesh of ``(len(devices), 1)``, built
+    once per process."""
+    from repro.launch.mesh import make_mesh
+    return make_mesh((len(devices), 1), ("data", "model"),
+                     devices=list(devices))
+
+
 def compactor(cfg: dict):
+    """The configuration's ``Compactor``; the sharded backend of a cell
+    on several chips gets a mesh over all of them (FSP rows over the
+    ``data`` axis)."""
     from repro.api import Compactor
-    return Compactor(detector=cfg["detector"], backend=cfg["backend"])
+    if cfg["backend"] != "sharded" or len(DEVICES) < 2:
+        return Compactor(detector=cfg["detector"], backend=cfg["backend"])
+    return Compactor(detector=cfg["detector"], backend=cfg["backend"],
+                     backend_opts={"mesh": _mesh(tuple(DEVICES))})
 
 
 def arrivals(r: np.random.Generator, rate: float, seconds: float

@@ -20,22 +20,26 @@ from __future__ import annotations
 import numpy as np
 
 
-def _key(rows: np.ndarray) -> np.ndarray:
-    rows = np.ascontiguousarray(rows, np.int32)
-    return rows.view([("", np.int32)] * rows.shape[1]).ravel()
+def _distinct(mat: np.ndarray) -> np.ndarray:
+    """The distinct rows of an ``(n, k)`` integer matrix, sorted: one
+    ``np.lexsort`` over the columns, then each row that differs from the
+    one before it."""
+    mat = mat[np.lexsort(mat.T[::-1])]
+    keep = np.ones(mat.shape[0], bool)
+    keep[1:] = (mat[1:] != mat[:-1]).any(axis=1)
+    return mat[keep]
 
 
 def n_distinct_rows(mat: np.ndarray) -> int:
-    if mat.shape[0] == 0:
-        return 0
-    return int(np.unique(_key(mat)).shape[0])
+    return int(_distinct(mat).shape[0])
 
 
 def rows_differ(a: np.ndarray, b: np.ndarray) -> int:
-    """Size of the symmetric difference of two row sets."""
-    ka, kb = np.unique(_key(a)), np.unique(_key(b))
-    both = np.intersect1d(ka, kb).shape[0]
-    return int(ka.shape[0] + kb.shape[0] - 2 * both)
+    """Size of the symmetric difference of two row sets: twice their
+    union less the sizes of both."""
+    da, db = _distinct(a), _distinct(b)
+    union = _distinct(np.concatenate([da, db]))
+    return int(2 * union.shape[0] - da.shape[0] - db.shape[0])
 
 
 class Triples:

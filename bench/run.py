@@ -148,8 +148,12 @@ def execute(spec: dict, wl: dict, cfg: dict, traffic: dict, *, seed: int,
     import jax
     sys.path.insert(0, os.path.join(ROOT, "src"))
     sys.path.insert(0, ROOT)
-    from bench import peaks, trace as tr
+    from bench import common, peaks, trace as tr
 
+    if len(devices) < int(wl["chips"]):
+        raise ValueError(f"{wl['name']} needs {wl['chips']} chips; the run "
+                         f"has {len(devices)} devices")
+    common.DEVICES[:] = devices[:int(wl["chips"])]
     state_dir = os.path.join(STATE, wl["name"])
     shutil.rmtree(state_dir, ignore_errors=True)
     os.makedirs(state_dir)
@@ -183,7 +187,9 @@ def execute(spec: dict, wl: dict, cfg: dict, traffic: dict, *, seed: int,
         reduced = tr.reduce_dir(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
     memory_peak = _memory_peak(devices)
+    t_check = time.monotonic()
     checks = driver.check(run, state)
+    check_s = time.monotonic() - t_check
     del state
     shutil.rmtree(state_dir, ignore_errors=True)
     correct = all(v <= lim for _, v, lim in checks)
@@ -218,8 +224,8 @@ def execute(spec: dict, wl: dict, cfg: dict, traffic: dict, *, seed: int,
     result["checks"] = {n: {"value": v, "limit": lim}
                         for n, v, lim in checks}
     print(f"bench: window {window_s:.3f} s, set-up {setup_s:.3f} s, "
-          f"{out['attempted']} attempted, {out['failed']} failed, "
-          f"generator late by up to "
+          f"check {check_s:.3f} s, {out['attempted']} attempted, "
+          f"{out['failed']} failed, generator late by up to "
           f"{run.counters.get('late_s', 0.0) * 1e3:.1f} ms, "
           f"{compiles.n} programs compiled or loaded in the window",
           file=sys.stderr)

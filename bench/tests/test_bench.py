@@ -6,13 +6,17 @@ Every workload resolves its files by name; each cell's run comes out
 correct, and its control (``bench/controls.py``: the answer altered
 where the program produces it) and each fault its timed path can have
 come out not correct; the trace reduction and the kernel byte count
-hold on a recorded trace.
+hold on a recorded trace; a sharded cell on four chips runs the sweep
+over a four-device mesh, and a cell with more chips than the run has
+is refused.
 """
 from __future__ import annotations
 
 import json
 import os
+import subprocess
 import sys
+import textwrap
 
 import pytest
 
@@ -164,3 +168,72 @@ def test_compact_readers_on_recorded_trace():
         assert v is not None and v > 0, m["name"]
         if m["unit"] == "%":
             assert v <= 100.0, m["name"]
+
+
+MESH_RUN = """
+import json, sys
+sys.path[:0] = [{root!r}, {src!r}]
+import jax
+from bench import controls, run as R
+from repro.core import sweep
+
+meshes = set()
+plain = sweep.ShardedSweepWorkspace._run_batch
+
+
+def spy(self, stack, n_sp):
+    meshes.add((0 if self.mesh is None else self.mesh.devices.size,
+                tuple(self.dp_axes)))
+    return plain(self, stack, n_sp)
+
+
+sweep.ShardedSweepWorkspace._run_batch = spy
+spec = R.load_spec()
+wl, cfg, traffic = R.resolve(spec, {cell!r})
+wl = dict(wl, chips=4)
+cfg = dict(cfg, **{small!r}, backend="sharded")
+
+
+def go():
+    return R.execute(spec, wl, cfg, traffic, seed=2**31 + 23, seconds=2.0,
+                     trace=False, devices=jax.devices())[0]
+
+
+ok = go()
+with controls.CONTROLS["compact"]():
+    bad = go()
+print(json.dumps({{"correct": ok["correct"], "checks": ok["checks"],
+                  "control_correct": bad["correct"],
+                  "meshes": sorted(meshes)}}))
+"""
+COMPACT_CELL = next(c for c in CELLS
+                    if R.resolve(SPEC, c)[2]["driver"] == "compact")
+
+
+def test_sharded_cell_on_four_chips_runs_over_a_four_device_mesh():
+    """A cell on four chips whose configuration names the sharded
+    backend compacts over a ``(4, 1)`` mesh of four (virtual) devices,
+    correct, and its control is not."""
+    code = textwrap.dedent(MESH_RUN.format(
+        root=ROOT, src=os.path.join(ROOT, "src"), cell=COMPACT_CELL,
+        small=SMALL))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["correct"], out["checks"]
+    assert not out["control_correct"]
+    assert out["meshes"] == [[4, ["data"]]]
+
+
+def test_cell_with_more_chips_than_the_run_fails_at_once():
+    import jax
+    wl, cfg, traffic = _small(COMPACT_CELL)
+    wl = dict(wl, chips=len(jax.devices()) + 1)
+    cfg = dict(cfg, backend="sharded")
+    with pytest.raises(ValueError, match="needs .* chips; the run has"):
+        R.execute(SPEC, wl, cfg, traffic, seed=2**31 + 11, seconds=2.0,
+                  trace=False, devices=jax.devices())
