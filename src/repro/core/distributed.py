@@ -93,8 +93,35 @@ def gfsp_distributed(store: TripleStore, class_id: int, *, mesh=None,
     return GreedyDetector().detect(store, class_id, backend=backend)
 
 
+# per-destination headroom of the hash-bucket exchange, in fair shares:
+# no signature can be dropped while it is at least the row-shard count
+CAP_FACTOR = 4.0
+
+
+def dp_shards(mesh, dp_axes: Sequence[str]) -> int:
+    """Number of row shards: the product of the mesh's ``dp_axes`` sizes."""
+    n = 1
+    for a, s_ in zip(mesh.axis_names, mesh.devices.shape):
+        if a in dp_axes:
+            n *= s_
+    return n
+
+
+def exchange_shape(n_rows: int, n_cands: int, n_shards: int,
+                   cap_factor: float = CAP_FACTOR
+                   ) -> tuple[int, int, int, int]:
+    """Static shape of the uint32 buffer each shard sends in
+    :func:`ami_bucketed_batch`'s ``all_to_all``: ``(n_shards, c, cap, 2)``
+    for ``n_rows`` rows over ``n_shards`` shards, with a per-destination
+    capacity of ``cap_factor`` times a fair share (plus 8 slots)."""
+    nl = n_rows // n_shards
+    cap = max(int(cap_factor * nl / n_shards) + 8, 8)
+    return n_shards, n_cands, cap, 2
+
+
 def ami_bucketed_batch(objmat, valid, col_masks, mesh, *, dp_axes=("data",),
-                       cap_factor: float = 4.0, use_kernel: bool = True):
+                       cap_factor: float = CAP_FACTOR,
+                       use_kernel: bool = True):
     """Candidate-batched distinct-row count via ONE hash-bucket exchange.
 
     The sort-based AMI is exact but a distributed sort exchanges the data
@@ -114,16 +141,16 @@ def ami_bucketed_batch(objmat, valid, col_masks, mesh, *, dp_axes=("data",),
 
     objmat: (n, k) int32 row-sharded over ``dp_axes``; valid: (n,) bool;
     col_masks: (C, k) int32 replicated column masks (1 = keep column).
-    Returns (C,) int32 AMI, one per candidate.
+    Returns ``(amis, dropped)``, both (C,) int32: the AMI per candidate,
+    and the signatures the exchange dropped for want of capacity, which
+    the AMI counts as distinct (an upper bound; exact when 0).  No shard
+    can overflow while ``cap_factor >= n_shards``.
     """
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ops as kops
 
-    n_shards = 1
-    for a, s_ in zip(mesh.axis_names, mesh.devices.shape):
-        if a in dp_axes:
-            n_shards *= s_
+    n_shards = dp_shards(mesh, dp_axes)
 
     def body(mat, val, masks):
         nl = mat.shape[0]
@@ -136,7 +163,7 @@ def ami_bucketed_batch(objmat, valid, col_masks, mesh, *, dp_axes=("data",),
         sentinel = jnp.uint32(kops.SIG_SENTINEL)
         owner = (sig[..., 0] % jnp.uint32(n_shards)).astype(jnp.int32)
         owner = jnp.where(val[None, :], owner, n_shards)  # invalid -> dump
-        cap = max(int(cap_factor * nl / n_shards) + 8, 8)
+        cap = exchange_shape(nl * n_shards, c, n_shards, cap_factor)[2]
         order = jnp.argsort(owner, axis=1)
         owner_s = jnp.take_along_axis(owner, order, axis=1)
         sig_s = jnp.take_along_axis(sig, order[..., None], axis=1)
@@ -166,24 +193,25 @@ def ami_bucketed_batch(objmat, valid, col_masks, mesh, *, dp_axes=("data",),
                            axis=-1)                                # (c,)
         local_distinct = n_groups - has_sent.astype(jnp.int32)
         total = jax.lax.psum(local_distinct, dp_axes)
-        total = total + jax.lax.psum(dropped, dp_axes)  # upper-bound fix
-        return total
+        dropped = jax.lax.psum(dropped, dp_axes)
+        total = total + dropped  # upper-bound fix
+        return total, dropped
 
     spec_m = P(dp_axes, None)
     spec_v = P(dp_axes)
     # check_vma=False: pallas_call outputs do not carry vma metadata yet
     return jax.shard_map(body, mesh=mesh,
                          in_specs=(spec_m, spec_v, P(None, None)),
-                         out_specs=P(None), check_vma=False)(
+                         out_specs=(P(None), P(None)), check_vma=False)(
         objmat, valid, col_masks)
 
 
 def ami_bucketed(objmat, valid, mesh, *, dp_axes=("data",),
-                 cap_factor: float = 4.0, use_kernel: bool = True):
+                 cap_factor: float = CAP_FACTOR, use_kernel: bool = True):
     """Single-candidate distinct-row count: the C = 1 special case of
     :func:`ami_bucketed_batch` with an all-ones column mask (kept as the
     stable entry point for callers outside the sweep engine)."""
     masks = jnp.ones((1, objmat.shape[1]), jnp.int32)
     return ami_bucketed_batch(
         objmat, valid, masks, mesh, dp_axes=dp_axes,
-        cap_factor=cap_factor, use_kernel=use_kernel)[0]
+        cap_factor=cap_factor, use_kernel=use_kernel)[0][0]

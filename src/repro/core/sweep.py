@@ -40,6 +40,7 @@ A :class:`SweepWorkspace` fixes both costs structurally:
 ``EXEC_STATS`` counts executed lowerings vs logical descents (one
 ``sweep``/``sweep_candidates`` call = one descent) -- the benchmark
 snapshot asserts one lowering per warm descent on the batched paths.
+``SHARD_STATS`` counts what the mesh-sharded batches exchange.
 """
 from __future__ import annotations
 
@@ -98,6 +99,14 @@ TRACE_COUNTS: dict[tuple, int] = {}
 # candidate stack that fits one chunk
 EXEC_STATS = {"lowerings": 0, "descents": 0}
 
+# mesh-sharded candidate batches (``ShardedSweepWorkspace`` on a mesh):
+# ``batches`` run, ``shards`` summed over them (``shards / batches`` is the
+# row-shard count), ``exchange_bytes`` each shard sends in the batches'
+# all_to_all (from the static buffer shape, summed over batches), and
+# ``dropped`` signatures the exchange had no room for (the AMI then
+# counts each as distinct: an upper bound, exact while this stays 0)
+SHARD_STATS = {"batches": 0, "shards": 0, "exchange_bytes": 0, "dropped": 0}
+
 
 def _note_trace(kind: str, shape: tuple) -> None:
     # executed at trace time only: jit cache hits never reach the body
@@ -126,6 +135,8 @@ def reset_trace_stats() -> None:
     TRACE_COUNTS.clear()
     EXEC_STATS["lowerings"] = 0
     EXEC_STATS["descents"] = 0
+    for k in SHARD_STATS:
+        SHARD_STATS[k] = 0
     for fn in _EXTRA_STAT_RESETS:
         fn()
 
@@ -189,16 +200,18 @@ def _sharded_sweep_fn(mesh, dp_axes: tuple, use_kernel: bool):
     """Jitted candidate-batch AMI through the explicit hash-bucket
     collective schedule (``core.distributed.ami_bucketed_batch``): the
     only distinct-count lowering that is exact on real multi-axis meshes,
-    now carrying the whole candidate stack through ONE all_to_all."""
+    now carrying the whole candidate stack through ONE all_to_all.
+    Returns ``(amis, dropped)``; its device program is
+    ``jit_sharded_sweep``."""
     jax, jnp = _jax()
     from .distributed import ami_bucketed_batch
 
-    def batch(objmat, valid, col_masks):
+    def sharded_sweep(objmat, valid, col_masks):
         _note_trace("sharded", objmat.shape + (col_masks.shape[0],))
         return ami_bucketed_batch(objmat, valid, col_masks, mesh,
                                   dp_axes=dp_axes, use_kernel=use_kernel)
 
-    return jax.jit(batch)
+    return jax.jit(sharded_sweep)
 
 
 # -- selection rule ----------------------------------------------------------
@@ -423,7 +436,7 @@ class ShardedSweepWorkspace(DeviceSweepWorkspace):
     chunk -- one shard_map lowering per descent, not one per candidate;
     the column-drop multiply happens inside the shard_map body with row
     sharding preserved, so the buffer still uploads exactly once per
-    descent.
+    descent.  Each batch is counted in ``SHARD_STATS``.
     """
 
     def __init__(self, store, class_id, props, n_s, am, *, mesh=None,
@@ -431,6 +444,7 @@ class ShardedSweepWorkspace(DeviceSweepWorkspace):
         self.mesh = mesh
         self.plan = plan
         self.dp_axes: tuple = ()
+        self.n_shards = 1
         super().__init__(store, class_id, props, n_s, am,
                          use_kernel=use_kernel)
 
@@ -439,6 +453,8 @@ class ShardedSweepWorkspace(DeviceSweepWorkspace):
             return 1, None
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.dist.sharding import batch_axes_for
+
+        from .distributed import dp_shards
         if self.plan is not None:
             # prefer the planner's rung for this (padded) row count
             axes = tuple(batch_axes_for(self.plan, bucket_rows(n_rows))
@@ -446,22 +462,25 @@ class ShardedSweepWorkspace(DeviceSweepWorkspace):
         else:
             axes = tuple(a for a in self.mesh.axis_names if a != "model")
         self.dp_axes = axes
-        row_multiple = int(np.prod(
-            [s for a, s in zip(self.mesh.axis_names,
-                               self.mesh.devices.shape) if a in axes],
-            initial=1))
-        return row_multiple, (NamedSharding(self.mesh, P(axes, None)),
-                              NamedSharding(self.mesh, P(axes)))
+        self.n_shards = dp_shards(self.mesh, axes)
+        return self.n_shards, (NamedSharding(self.mesh, P(axes, None)),
+                               NamedSharding(self.mesh, P(axes)))
 
     def _run_batch(self, stack: np.ndarray, n_sp: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
         if self.mesh is None:
             return super()._run_batch(stack, n_sp)
+        from .distributed import exchange_shape
         _, jnp = _jax()
         EXEC_STATS["lowerings"] += 1
         fn = _sharded_sweep_fn(self.mesh, self.dp_axes, self.use_kernel)
         with span("sweep.batch", key=self.class_id):
-            amis = np.asarray(fn(self._dev, self._valid,
-                                 jnp.asarray(stack)))
+            amis, dropped = fn(self._dev, self._valid, jnp.asarray(stack))
+            amis, dropped = np.asarray(amis), np.asarray(dropped)
+        SHARD_STATS["batches"] += 1
+        SHARD_STATS["shards"] += self.n_shards
+        SHARD_STATS["exchange_bytes"] += 4 * int(np.prod(exchange_shape(
+            self.n_bucket, stack.shape[0], self.n_shards)))
+        SHARD_STATS["dropped"] += int(dropped.sum())
         edges = num_edges_batch(amis, self.am, n_sp, self.n_s)
         return edges, amis
