@@ -37,15 +37,20 @@ import numpy as np
 SPO_PERM = (0, 1, 2)      # TripleStore.spo canonical order
 PSO_PERM = (1, 0, 2)      # GraphIndex row order
 
-# Row keys.  Sorting and binary search compare rows as one key.  Where
-# every id is >= 0 and fits _PACK_MAX_BITS bits, the key is the three
-# columns packed into one uint64, (c0 << 2b) | (c1 << b) | c2 in ``perm``
-# order, whose unsigned order is the rows' lexicographic order; NumPy
-# sorts and searches it natively.  Wider ids take an exact fallback:
-# ``np.lexsort`` for sorts and a structured (void) key for searches,
-# both compared through a generic per-element callback, an order of
-# magnitude slower.  Both sides of a search are keyed at one shared width.
-_PACK_MAX_BITS = 21       # 3 * 21 = 63 bits
+# Row keys.  Sorting and binary search compare rows as one key.  Each
+# column gets its own bit width w_i, the bit length of the largest id in
+# that column over every array keyed together, so both sides of a search
+# share one encoding.  Where every id is >= 0 and
+# w0 + w1 + w2 <= _PACK_MAX_BITS, the key is the three columns packed
+# into one uint64, (c0 << (w1 + w2)) | (c1 << w2) | c2 in ``perm`` order,
+# whose unsigned order is the rows' lexicographic order; NumPy sorts and
+# searches it natively.  Few predicates keep their field narrow: 22-bit
+# subjects and objects with 4-bit predicates need 48 bits.  Only
+# negative ids or widths that sum past 64 bits (e.g. ids near 2^31 in
+# every column) take the exact fallback: ``np.lexsort`` for sorts and a
+# structured (void) key for searches, both compared through a generic
+# per-element callback, an order of magnitude slower.
+_PACK_MAX_BITS = 64
 _KEY_DTYPE = np.dtype([("a", np.int32), ("b", np.int32), ("c", np.int32)])
 
 # keyed sorts and searches by path: the store's engagement counter
@@ -57,35 +62,38 @@ def reset_key_stats() -> None:
     KEY_STATS["wide"] = 0
 
 
-def _pack_width(arrays) -> int | None:
-    """Shared bit width of packed keys over every id of ``arrays``, or
-    None (the wide path) if an id is negative or needs more than
-    ``_PACK_MAX_BITS`` bits.  Counts the keyed operation in KEY_STATS."""
-    lo = hi = 0
+def _pack_widths(arrays) -> tuple[int, int, int] | None:
+    """Per-column bit widths of packed keys over every id of ``arrays``,
+    or None (the wide path) if an id is negative or the widths sum past
+    ``_PACK_MAX_BITS``.  Counts the keyed operation in KEY_STATS."""
+    lo = 0
+    hi = [0, 0, 0]
     for a in arrays:
         if a.size:
             lo = min(lo, int(a.min()))
-            hi = max(hi, int(a.max()))
-    if lo < 0 or hi >> _PACK_MAX_BITS:
+            hi = [max(h, int(a[:, j].max())) for j, h in enumerate(hi)]
+    widths = tuple(h.bit_length() for h in hi)
+    if lo < 0 or sum(widths) > _PACK_MAX_BITS:
         KEY_STATS["wide"] += 1
         return None
     KEY_STATS["packed"] += 1
-    return max(hi.bit_length(), 1)
+    return widths
 
 
-def _pack(rows: np.ndarray, perm, b: int) -> np.ndarray:
-    key = rows[:, perm[0]].astype(np.uint64) << np.uint64(2 * b)
-    key |= rows[:, perm[1]].astype(np.uint64) << np.uint64(b)
+def _pack(rows: np.ndarray, perm, widths) -> np.ndarray:
+    w1, w2 = widths[perm[1]], widths[perm[2]]
+    key = rows[:, perm[0]].astype(np.uint64) << np.uint64(w1 + w2)
+    key |= rows[:, perm[1]].astype(np.uint64) << np.uint64(w2)
     key |= rows[:, perm[2]].astype(np.uint64)
     return key
 
 
-def _unpack(key: np.ndarray, perm, b: int) -> np.ndarray:
+def _unpack(key: np.ndarray, perm, widths) -> np.ndarray:
+    w1, w2 = widths[perm[1]], widths[perm[2]]
     rows = np.empty((key.shape[0], 3), np.int32)
-    mask = np.uint64((1 << b) - 1)
-    rows[:, perm[0]] = key >> np.uint64(2 * b)
-    rows[:, perm[1]] = (key >> np.uint64(b)) & mask
-    rows[:, perm[2]] = key & mask
+    rows[:, perm[0]] = key >> np.uint64(w1 + w2)
+    rows[:, perm[1]] = (key >> np.uint64(w2)) & np.uint64((1 << w1) - 1)
+    rows[:, perm[2]] = key & np.uint64((1 << w2) - 1)
     return rows
 
 
@@ -100,13 +108,13 @@ def _sorted(rows: np.ndarray, perm, unique: bool) -> np.ndarray:
     rows = np.ascontiguousarray(rows, dtype=np.int32).reshape(-1, 3)
     if rows.shape[0] <= 1:
         return rows
-    b = _pack_width((rows,))
-    if b is not None:
-        key = _pack(rows, perm, b)
+    widths = _pack_widths((rows,))
+    if widths is not None:
+        key = _pack(rows, perm, widths)
         key.sort()
         if unique:
             key = key[np.append(True, key[1:] != key[:-1])]
-        return _unpack(key, perm, b)
+        return _unpack(key, perm, widths)
     rows = rows[np.lexsort((rows[:, perm[2]], rows[:, perm[1]],
                             rows[:, perm[0]]))]
     if unique:
@@ -127,9 +135,9 @@ def sort_unique(rows: np.ndarray, perm=SPO_PERM) -> np.ndarray:
 
 def _row_keys(perm, *arrays) -> list[np.ndarray]:
     """Searchable keys of each (n, 3) row array, all at one encoding."""
-    b = _pack_width(arrays)
-    if b is not None:
-        return [_pack(a, perm, b) for a in arrays]
+    widths = _pack_widths(arrays)
+    if widths is not None:
+        return [_pack(a, perm, widths) for a in arrays]
     return [_key_view(a, perm) for a in arrays]
 
 

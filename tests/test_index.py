@@ -170,16 +170,25 @@ def _ref_merge(old, new, perm):
     return _ref_sort(np.concatenate([old, new]), perm, unique=True)
 
 
-_WIDTHS = {"narrow": (0, 2**19 - 1), "boundary": (0, 2**21 - 1),
-           "wide": (0, 2**31 - 1), "negative": (-2**20, 2**20)}
+# id ranges, one for all columns or one per (s, p, o) column, and the key
+# path each must take: per-column bit widths summing to at most 64 pack
+_WIDTHS = {"narrow": [(0, 2**19 - 1)], "boundary": [(0, 2**21 - 1)],
+           "wide": [(0, 2**31 - 1)], "negative": [(-2**20, 2**20)],
+           "x6": [(0, 2**22 - 1), (0, 2**4 - 1), (0, 2**22 - 1)],
+           "sum64": [(0, 2**31 - 1), (0, 2**2 - 1), (0, 2**31 - 1)],
+           "sum65": [(0, 2**31 - 1), (0, 2**3 - 1), (0, 2**31 - 1)]}
+_PATH = {"narrow": "packed", "boundary": "packed", "wide": "wide",
+         "negative": "wide", "x6": "packed", "sum64": "packed",
+         "sum65": "wide"}
 
 
 def _rows_at(rng, width, n, pool=24):
-    """Rows over a small pool of ids spanning ``width``, both ends
-    included, so duplicates and shared prefixes occur."""
-    lo, hi = _WIDTHS[width]
-    ids = np.concatenate([[lo, hi], rng.integers(lo, hi, pool - 2)])
-    return ids[rng.integers(0, pool, (n, 3))].astype(np.int32)
+    """Rows over a small pool of ids per column spanning ``width``, both
+    ends included, so duplicates and shared prefixes occur."""
+    ids = np.stack([np.concatenate([[lo, hi], rng.integers(lo, hi, pool - 2)])
+                    for lo, hi in _WIDTHS[width]], axis=1)
+    idx = rng.integers(0, pool, (n, 3))
+    return np.take_along_axis(ids, idx, axis=0).astype(np.int32)
 
 
 def _case_rows(rng, width, shape, perm):
@@ -198,12 +207,13 @@ def _assert_same(got, want):
 
 
 @pytest.mark.parametrize("shape", ["duplicates", "sorted", "tiny"])
-@pytest.mark.parametrize("width", ["narrow", "boundary", "wide", "negative"])
+@pytest.mark.parametrize("width", list(_WIDTHS))
 @pytest.mark.parametrize("perm", [SPO_PERM, PSO_PERM], ids=["spo", "pso"])
 def test_row_keys_match_structured_reference(perm, width, shape):
     rng = np.random.default_rng(sum(map(ord, width + shape)) + perm[0])
     other = _rows_at(rng, width, 300)
     old = _ref_sort(other, perm, unique=True)
+    before = dict(KEY_STATS)
     for rows in _case_rows(rng, width, shape, perm):
         new = _ref_sort(rows, perm, unique=True)
         _assert_same(sort_unique(rows, perm), new)
@@ -219,14 +229,19 @@ def test_row_keys_match_structured_reference(perm, width, shape):
                      _ref_sort(rows, PSO_PERM, unique=False))
         _assert_same(GraphIndex(old, 0, 1).merged(rows).rows,
                      _ref_merge(old, rows, PSO_PERM))
+    path = _PATH[width]
+    other_path = "wide" if path == "packed" else "packed"
+    assert KEY_STATS[path] > before[path]
+    assert KEY_STATS[other_path] == before[other_path]
 
 
 @pytest.mark.parametrize("old_hi", [2**19 - 1, 2**31 - 1],
                          ids=["packed", "wide"])
 @pytest.mark.parametrize("perm", [SPO_PERM, PSO_PERM], ids=["spo", "pso"])
 def test_row_keys_share_one_width_across_both_sides(perm, old_hi):
-    """``new`` alone would pack at 5 bits; ``old`` needs 19 bits or the
-    wide path.  Keyed apart, equal rows would get unequal keys."""
+    """``new`` alone would pack at 5 bits a column; ``old`` needs 19 bits
+    a column or the wide path.  Keyed apart, equal rows would get unequal
+    keys."""
     rng = np.random.default_rng(old_hi % 97)
     new = sort_unique(rng.integers(0, 32, (200, 3)).astype(np.int32), perm)
     far = np.array([[old_hi, 3, 4], [5, old_hi, 6], [7, 8, old_hi]],
@@ -242,10 +257,31 @@ def test_row_keys_share_one_width_across_both_sides(perm, old_hi):
     assert KEY_STATS[path] - before[path] == 3
 
 
+def _with_ids_past(store, n_pad):
+    """``store`` relabelled through a dictionary that puts its predicates
+    first and ``n_pad`` filler terms before every other term, as the
+    benchmark's generator lays out its schema: small predicate ids,
+    subjects and objects past ``n_pad``."""
+    terms = [store.dict.term(i) for i in range(len(store.dict))]
+    low = list(dict.fromkeys([store.TYPE, store.INSTANCE_OF,
+                              *np.unique(store.spo[:, 1]).tolist()]))
+    order = low + sorted(set(range(len(terms))) - set(low))
+    new_id = np.empty(len(terms), np.int32)
+    new_id[order] = np.arange(len(terms), dtype=np.int32)
+    new_id[order[len(low):]] += n_pad
+    d = TermDict.from_terms([terms[i] for i in low]
+                            + [f"ent/pad/{i}" for i in range(n_pad)]
+                            + [terms[i] for i in order[len(low):]])
+    return TripleStore.from_ids(d, new_id[store.spo])
+
+
 def test_key_stats_count_paths_and_reset():
     """Ids in the benchmark's range (402,629 terms, under 2^19) take only
-    the packed path, through a whole compaction; one id at 2^21 takes
-    the wide path; ``reset_trace_stats`` zeroes both."""
+    the packed path, through a whole compaction, and so do subjects and
+    objects past 2^21 beside small predicates (48-bit keys at 22 bits);
+    an id at 2^21 alone still packs, while a row whose column widths sum
+    past 64 bits, or a negative id, takes the wide path;
+    ``reset_trace_stats`` zeroes both."""
     core_sweep.reset_trace_stats()
     assert KEY_STATS == {"packed": 0, "wide": 0}
     store = _random_store(seed=4, n=200)
@@ -257,10 +293,27 @@ def test_key_stats_count_paths_and_reset():
                             np.full(50, c0, np.int32)], axis=1))
     Compactor(detector="gfsp", backend="host").run(store)
     assert KEY_STATS["packed"] > 0 and KEY_STATS["wide"] == 0
-    wide = TripleStore.from_ids(
-        store.dict, np.concatenate([store.spo, [[2**21, 0, 0]]]))
-    assert wide.index.rows.shape[0] == store.n_triples + 1
-    assert KEY_STATS["wide"] == 2        # the (s, p, o) and (p, s, o) sorts
+    for far, path in (([2**21, 0, 0], "packed"),
+                      ([2**31 - 1, 2**31 - 1, 2**31 - 1], "wide"),
+                      ([-1, 0, 0], "wide")):
+        before = dict(KEY_STATS)
+        grown = TripleStore.from_ids(
+            store.dict, np.concatenate([store.spo, [far]]))
+        assert grown.index.rows.shape[0] == store.n_triples + 1
+        # the (s, p, o) and (p, s, o) sorts
+        assert {k: KEY_STATS[k] - before[k] for k in KEY_STATS} == \
+            {"packed": 2 * (path == "packed"), "wide": 2 * (path == "wide")}
+    core_sweep.reset_trace_stats()
+    assert KEY_STATS == {"packed": 0, "wide": 0}
+    source = _random_store(seed=4, n=200)
+    x6 = _with_ids_past(source, 2**21)
+    assert int(x6.spo[:, [0, 2]].min()) >= 2**21
+    assert int(x6.spo[:, 1].max()) < 2**4
+    want = Compactor(detector="gfsp", backend="host").run(source)
+    core_sweep.reset_trace_stats()
+    got = Compactor(detector="gfsp", backend="host").run(x6)
+    assert got.n_triples_after == want.n_triples_after < x6.n_triples
+    assert KEY_STATS["packed"] > 0 and KEY_STATS["wide"] == 0
     core_sweep.reset_trace_stats()
     assert KEY_STATS == {"packed": 0, "wide": 0}
 
